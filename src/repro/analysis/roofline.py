@@ -36,15 +36,8 @@ LINK_BW = DEFAULT_LINK_BW  # bytes/s per ICI link per direction (repro.network)
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalised across jax versions.
-
-    Older jax returns a list with one dict per program; newer returns the
-    dict directly.  Always returns a (possibly empty) dict.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+    """``compiled.cost_analysis()`` as a (possibly empty) dict."""
+    return dict(compiled.cost_analysis() or {})
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,

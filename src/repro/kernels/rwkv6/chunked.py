@@ -15,7 +15,11 @@ product.  The (Q, Q, P) intra tensor is VPU work; Q=32, P=64 keeps it at
 256 KiB in VMEM.  (Production refinement: 16-token sub-chunk anchoring
 turns the off-diagonal blocks into MXU matmuls — see DESIGN.md §Kernels.)
 
-The state-in/state-out terms are (Q,P)x(P,P) matmuls on the MXU.
+The state-in/state-out terms are (Q,P)x(P,P) matmuls on the MXU.  The
+cumulative log decay is a lower-triangular matmul at full f32 precision
+(Mosaic has no in-kernel ``cumsum``), and ``u`` arrives as an (H, 1, P)
+array so its block is the (1, P) tail of the array, as the (8, 128) tiling
+rule allows.
 """
 
 from __future__ import annotations
@@ -28,11 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _rwkv6_kernel(
-    r_ref, k_ref, v_ref, lw_ref, u_ref,  # (Q,P) tiles; u: (P,)
+    r_ref, k_ref, v_ref, lw_ref, u_ref,  # (Q,P) tiles; u: (1,P)
     o_ref, sf_ref,  # outputs: (Q,P) tile; (P,P) final state
     state_scr,  # VMEM scratch (P,P)
     *,
@@ -52,31 +54,39 @@ def _rwkv6_kernel(
     lw = lw_ref[...].astype(jnp.float32)
     u = u_ref[...].astype(jnp.float32)
 
-    clw = jnp.cumsum(lw, axis=0)  # (Q,P)
+    tril = (
+        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+        >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    ).astype(jnp.float32)
+    clw = jax.lax.dot_general(
+        tril, lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )  # (Q,P) cumulative log decay (includes t)
+    total = jnp.sum(lw, axis=0, keepdims=True)  # (1,P) the chunk's log decay
     dec_in = jnp.exp(clw - lw)  # e^{clw_{t-1}} <= 1
-    state = state_scr[...]
-    # inter-chunk (MXU): (Q,P) @ (P,P)
+    state_t = state_scr[...]  # S^T: (value channel, key channel)
+    # inter-chunk (MXU): (Q,P) @ S
     o_inter = jax.lax.dot_general(
-        r * dec_in, state, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        r * dec_in, state_t, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
     # intra-chunk, direct bounded-exponent form (VPU)
     diff = (clw - lw)[:, None, :] - clw[None, :, :]  # (Q,Q,P), t x s
-    mask = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) > jax.lax.broadcasted_iota(
-        jnp.int32, (Q, Q), 1
-    )
-    expdiff = jnp.where(mask[:, :, None], jnp.exp(diff), 0.0)
-    scores = jnp.einsum("tp,sp,tsp->ts", r, k, expdiff)
-    diag = jnp.sum(r * u[None, :] * k, axis=1)  # (Q,)
+    mask = jax.lax.broadcasted_iota(jnp.int32, (Q, Q, P), 0) > jax.lax.broadcasted_iota(
+        jnp.int32, (Q, Q, P), 1
+    )  # s < t
+    expdiff = jnp.where(mask, jnp.exp(diff), 0.0)
+    scores = jnp.sum(r[:, None, :] * k[None, :, :] * expdiff, axis=2)  # (Q,Q)
+    diag = jnp.sum(r * u * k, axis=1, keepdims=True)  # (Q,1)
     o_intra = jax.lax.dot_general(
         scores, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    o_intra = o_intra + diag[:, None] * v
+    o_intra = o_intra + diag * v
     o_ref[...] = (o_inter + o_intra).astype(o_ref.dtype)
-    # state update (MXU): S' = diag(e^{clw_Q}) S + (k ⊙ e^{clw_Q-clw})^T v
-    dec_all = jnp.exp(clw[-1])  # (P,)
-    carry_k = k * jnp.exp(clw[-1][None, :] - clw)  # (Q,P)
-    state_new = state * dec_all[:, None] + jax.lax.dot_general(
-        carry_k, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    # state update (MXU), kept transposed so the key-channel decay scales
+    # lanes: S'^T = S^T diag(e^{clw_Q}) + v^T (k ⊙ e^{clw_Q-clw})
+    carry_k = k * jnp.exp(total - clw)  # (Q,P)
+    state_new = state_t * jnp.exp(total) + jax.lax.dot_general(
+        v, carry_k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     state_scr[...] = state_new
 
@@ -108,7 +118,7 @@ def rwkv6_chunked_hmajor(
             pl.BlockSpec((None, None, Q, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((None, None, Q, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((None, None, Q, P), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((None, P), lambda b, h, c: (h, 0)),
+            pl.BlockSpec((None, 1, P), lambda b, h, c: (h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, None, Q, P), lambda b, h, c: (b, h, c, 0)),
@@ -119,9 +129,9 @@ def rwkv6_chunked_hmajor(
             jax.ShapeDtypeStruct((B, H, P, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, P), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(r, k, v, logw, u)
-    return out, state
+    )(r, k, v, logw, u.reshape(H, 1, P))
+    return out, state.swapaxes(-1, -2)

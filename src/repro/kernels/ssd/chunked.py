@@ -12,6 +12,10 @@ per-head SSM state in VMEM scratch.  Unlike RWKV6, the SSD decay is a
 
 B/C group handling (n_groups < heads) is done in the BlockSpec index map
 (head h reads group h // (H/G)) — no materialised repetition in HBM.
+
+The cumulative log decay is a lower-triangular matmul at full f32
+precision (Mosaic has no in-kernel ``cumsum``); its row form comes from the
+transposed product, so no in-kernel transpose is needed either.
 """
 
 from __future__ import annotations
@@ -24,7 +28,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+
+def _matmul(a, b, contract):
+    """Full-f32 matmul: the prefix sums of log decays feed ``exp``."""
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )
 
 
 def _ssd_kernel(
@@ -42,34 +52,36 @@ def _ssd_kernel(
         state_scr[...] = jnp.zeros_like(state_scr)
 
     xw = xw_ref[...].astype(jnp.float32)  # dt-weighted inputs (Q,P)
-    la = la_ref[...].astype(jnp.float32)[:, 0]  # (Q,) log decay per step
+    la = la_ref[...].astype(jnp.float32)  # (Q,1) log decay per step
     bm = b_ref[...].astype(jnp.float32)  # (Q,N)
     cm = c_ref[...].astype(jnp.float32)  # (Q,N)
 
-    cla = jnp.cumsum(la)  # (Q,) cumulative log decay (includes t)
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    mask = row >= col  # s <= t
+    # cumulative log decay (includes t) as a column, the same as a row, and
+    # the decay still to come after s (cla_Q - cla_s) as a column
+    cla = _matmul(mask.astype(jnp.float32), la, ((1,), (0,)))  # (Q,1)
+    cla_row = _matmul(la, (row <= col).astype(jnp.float32), ((0,), (0,)))  # (1,Q)
+    rest = _matmul((row < col).astype(jnp.float32), la, ((1,), (0,)))  # (Q,1)
     state = state_scr[...]
     # inter-chunk
     y_inter = jax.lax.dot_general(
-        cm * jnp.exp(cla)[:, None], state, (((1,), (0,)), ((), ())),
+        cm * jnp.exp(cla), state, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     # intra-chunk
     scores = jax.lax.dot_general(
         cm, bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (Q,Q) = C B^T
-    diff = cla[:, None] - cla[None, :]
-    mask = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= jax.lax.broadcasted_iota(
-        jnp.int32, (Q, Q), 1
-    )
-    L = jnp.where(mask, jnp.exp(diff), 0.0)
+    L = jnp.where(mask, jnp.exp(cla - cla_row), 0.0)
     y_intra = jax.lax.dot_general(
         scores * L, xw, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     y_ref[...] = (y_inter + y_intra).astype(y_ref.dtype)
     # state update
-    dec_all = jnp.exp(cla[-1])
-    carry_b = bm * jnp.exp(cla[-1] - cla)[:, None]  # (Q,N)
-    state_new = state * dec_all + jax.lax.dot_general(
+    carry_b = bm * jnp.exp(rest)  # (Q,N)
+    state_new = state * jnp.exp(jnp.sum(la)) + jax.lax.dot_general(
         carry_b, xw, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     state_scr[...] = state_new
@@ -114,7 +126,7 @@ def ssd_chunked_hmajor(
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,30 +1,40 @@
-"""Batched serving driver: prefill + decode with continuous batching slots.
+"""Batched serving driver: greedy generation for a fixed batch of requests.
 
-CPU-scale with reduced configs; the production mesh path is exercised by
-the dry-run (decode_32k / long_500k cells lower ``decode_step``).
+All requests share one prompt length and one dense cache, and advance
+together: the prompt is fed through the cache one token per
+``decode_step`` (teacher-forced prefill), then every request decodes
+``--gen-len`` tokens greedily.  Runs on the default device; ``--full``
+serves the published widths and depth, the default is the reduced
+same-family config.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch granite-3-8b --reduced \
+  PYTHONPATH=src python -m repro.launch.serve --arch granite-3-8b \
       --requests 8 --prompt-len 16 --gen-len 24
+  PYTHONPATH=src python -m repro.launch.serve --arch zamba2-2.7b --full \
+      --requests 8 --prompt-len 128 --gen-len 32
 """
 
 from __future__ import annotations
 
 import argparse
+from dataclasses import dataclass
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_arch
+from repro.configs import ArchConfig, get_arch
 from repro.obs import timer as obs_timer
-from repro.models import build_model
-from repro.train import make_decode_step
+from repro.models import Model, build_model
+from repro.utils.env import enable_compile_cache
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b")
     ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="serve the published widths and depth")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen-len", type=int, default=24)
@@ -35,28 +45,43 @@ def main(argv=None):
              "arch at the given chip budget, then exit (no model is built)",
     )
     ap.add_argument("--plan-shape", default="decode_32k")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    if args.plan_chips is not None:
-        from repro.launch.planner import format_table, plan_model
 
-        plan = plan_model(
-            args.arch, args.plan_chips, shape=args.plan_shape, simulate_top_k=1
-        )
-        print(format_table(plan))
-        return plan
-
+def resolve_arch(args: argparse.Namespace) -> ArchConfig:
+    """The served config: published, or its reduced variant by default."""
     arch = get_arch(args.arch)
     if args.reduced:
         arch = arch.reduced()
     if arch.frontend != "none":
         raise SystemExit("serve driver supports token LMs (use token archs)")
+    return arch
+
+
+@dataclass
+class Served:
+    """What one :func:`serve` call produced."""
+
+    model: Model
+    params: Any
+    prompts: np.ndarray  # (B, prompt_len) int32
+    prefill_logits: jax.Array  # (B, padded vocab): logits at the last prompt position
+    tokens: np.ndarray  # (B, gen_len) generated ids
+    prefill_s: float
+    decode_s: float
+
+
+def serve(args: argparse.Namespace) -> Served:
+    """Build the model (parameters initialised under ``jit``), prefill the
+    seeded prompts through the cache and decode greedily."""
+    arch = resolve_arch(args)
     model = build_model(arch)
-    params = model.init(jax.random.key(args.seed))
+    params = jax.jit(model.init)(jax.random.key(args.seed))
     B = args.requests
-    max_len = args.prompt_len + args.gen_len
-    cache = model.init_cache(B, max_len)
-    decode = jax.jit(model.decode_step)
+    cache = model.init_cache(B, args.prompt_len + args.gen_len)
+    # the cache is donated: each step updates it in place instead of
+    # keeping a second copy alive
+    decode = jax.jit(model.decode_step, donate_argnums=1)
 
     rng = np.random.default_rng(args.seed)
     prompts = rng.integers(0, arch.vocab_size, (B, args.prompt_len), dtype=np.int32)
@@ -69,7 +94,8 @@ def main(argv=None):
                 params, cache, {"tokens": jnp.asarray(prompts[:, t : t + 1])}, jnp.array(t)
             )
         jax.block_until_ready(logits)
-    t_prefill = tm.elapsed
+    prefill_s = tm.elapsed
+    prefill_logits = logits[:, -1]
 
     # batched greedy decode
     out_tokens = []
@@ -82,18 +108,44 @@ def main(argv=None):
             )
             tok = jnp.argmax(logits[:, -1, : arch.vocab_size], axis=-1)[:, None].astype(jnp.int32)
         jax.block_until_ready(tok)
-    t_decode = tm.elapsed
+    decode_s = tm.elapsed
 
-    gen = np.concatenate(out_tokens, axis=1)
-    tps = B * args.gen_len / t_decode
-    print(f"arch={arch.name} requests={B} prompt={args.prompt_len} gen={args.gen_len}")
-    print(f"prefill {t_prefill*1e3:.1f} ms; decode {t_decode*1e3:.1f} ms "
+    return Served(
+        model=model,
+        params=params,
+        prompts=prompts,
+        prefill_logits=prefill_logits,
+        tokens=np.concatenate(out_tokens, axis=1),
+        prefill_s=prefill_s,
+        decode_s=decode_s,
+    )
+
+
+def main(argv=None):
+    args = parse_args(argv)
+
+    if args.plan_chips is not None:
+        from repro.launch.planner import format_table, plan_model
+
+        plan = plan_model(
+            args.arch, args.plan_chips, shape=args.plan_shape, simulate_top_k=1
+        )
+        print(format_table(plan))
+        return plan
+
+    enable_compile_cache()
+    out = serve(args)
+    arch = out.model.cfg
+    B, gen = out.tokens.shape
+    tps = B * gen / out.decode_s
+    print(f"arch={arch.name} requests={B} prompt={args.prompt_len} gen={gen}")
+    print(f"prefill {out.prefill_s*1e3:.1f} ms; decode {out.decode_s*1e3:.1f} ms "
           f"({tps:.1f} tok/s aggregate)")
     print("sample generations (token ids):")
     for b in range(min(B, 3)):
-        print(f"  req{b}: {gen[b, :12].tolist()}...")
-    assert gen.shape == (B, args.gen_len)
-    assert int(gen.max()) < arch.vocab_size
+        print(f"  req{b}: {out.tokens[b, :12].tolist()}...")
+    assert out.tokens.shape == (B, args.gen_len)
+    assert int(out.tokens.max()) < arch.vocab_size
     return tps
 
 

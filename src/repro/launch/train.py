@@ -1,10 +1,10 @@
 """End-to-end training driver.
 
-Runs real training on the available devices (CPU-scale with reduced configs;
-the same code path jits under the production mesh on TPU).  Integrates the
-full substrate: deterministic sharded data pipeline, microbatched AdamW,
-async checkpointing with restart, failure injection + supervisor restore,
-straggler tracking, and optional cross-pod gradient compression.
+Trains on the default device under a plain ``jax.jit`` (no mesh, no
+sharding); ``--full`` trains the published widths and depth, the default is
+the reduced same-family config.  Integrates the substrate: deterministic
+data pipeline, microbatched AdamW, async checkpointing with restart,
+failure injection + supervisor restore, and straggler tracking.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch granite-3-8b --reduced \
@@ -28,9 +28,9 @@ from repro.data import DataConfig, DataPipeline
 from repro.models import build_model
 from repro.obs import timer as obs_timer
 from repro.optim import AdamWConfig, adamw
-from repro.optim import compression as comp
 from repro.runtime import HeartbeatMonitor, StragglerTracker
 from repro.train import make_train_step
+from repro.utils.env import enable_compile_cache
 
 
 def main(argv=None):
@@ -47,7 +47,6 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--simulate-failure-at", type=int, default=None)
-    ap.add_argument("--compress", choices=["none", "int8", "topk"], default="none")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument(
@@ -67,6 +66,7 @@ def main(argv=None):
         print(format_table(plan))
         return plan
 
+    enable_compile_cache()
     arch = get_arch(args.arch)
     if args.reduced:
         arch = arch.reduced()
@@ -85,8 +85,6 @@ def main(argv=None):
     if mgr and args.resume and mgr.latest_step() is not None:
         start_step, (params, opt_state) = mgr.restore((params, opt_state))
         print(f"resumed from checkpoint step {start_step}")
-
-    comp_state = comp.init_state(params) if args.compress != "none" else None
 
     data_cfg = DataConfig(seed=args.seed, global_batch=args.batch, seq_len=args.seq)
     pipeline = DataPipeline(arch, data_cfg, start_step=start_step)
@@ -116,10 +114,6 @@ def main(argv=None):
             dt = tm.elapsed
             straggler.record("w0", dt)
             losses.append(loss)
-            if args.compress != "none":
-                # demonstrate the cross-pod path: compress the params delta
-                # that WOULD cross the DCI (accounting only on 1 host)
-                pass
             if step % args.log_every == 0:
                 print(
                     f"step {step:5d} loss {loss:7.4f} "

@@ -12,7 +12,7 @@ per Python-loop iteration:
 ``xla``        ``jax.jit`` ports: the DOR difference-array link-load tensor
                (:func:`xla_route_loads`), max-min progressive filling as a
                fixed-shape masked ``lax.while_loop`` (:func:`prepare_drain`
-               / :func:`drain`), the FFT contention cross-correlation
+               / :func:`drain`), the integer contention cross-correlation
                (:func:`xla_contention_field`), the closed-form cut scoring
                (:func:`xla_cut_scores`), and the ``vmap``-batched candidate
                scorer (:func:`score_candidates`).
@@ -25,13 +25,15 @@ Selection: every threaded entry point takes ``backend=None``, resolved by
 :func:`resolve_backend` — an explicit argument wins, else the
 ``REPRO_NETWORK_BACKEND`` environment variable, else ``numpy``.
 
-Exactness contract.  The xla backend pins ``jax_enable_x64`` (via
-:mod:`repro.utils.env`) on first use, because parity is bit-meaningful:
-link loads are sums of integer (or tie-halved dyadic) volumes, so the
-``numpy`` and ``xla`` load tensors are **equal exactly**, not merely
-close.  Max-min rates and makespans agree to <= 1e-9 relative (XLA's
-multiply-add fusion reorders a handful of float ops); the property suite
-in ``tests/test_backend.py`` pins both, and
+Exactness contract.  Every xla program is traced, fed and run under
+``jax.enable_x64`` (:func:`_x64`), scoped to the backend's own calls so
+model code in the same process keeps 32-bit defaults.  Parity is
+bit-meaningful: link loads are sums of integer (or tie-halved dyadic)
+volumes, so the ``numpy`` and ``xla`` load tensors are **equal exactly**,
+not merely close, and the contention field is an exact integer
+correlation on both sides.  Max-min rates and makespans agree to <= 1e-9
+relative (XLA's multiply-add fusion reorders a handful of float ops); the
+property suite in ``tests/test_backend.py`` pins both, and
 ``benchmarks/bench_backend.py`` gates the >= 10x throughput claims.
 
 What stays NumPy and why: host-side path building and ELL compaction
@@ -86,17 +88,20 @@ _JAX: Optional[tuple] = None
 
 
 def _jax():
-    """Import jax lazily, enabling x64 first (the exactness contract)."""
+    """Import jax lazily (importing this module never imports jax)."""
     global _JAX
     if _JAX is None:
-        from ..utils.env import jax_enable_x64
-
-        jax_enable_x64(True)
         import jax
         import jax.numpy as jnp
 
         _JAX = (jax, jnp)
     return _JAX
+
+
+def _x64():
+    """The context every compiled-backend program is traced, fed and run
+    in: 64-bit types for the exactness contract, scoped to the call."""
+    return _jax()[0].enable_x64(True)
 
 
 def resolve_backend(backend: Optional[str] = None) -> str:
@@ -151,12 +156,13 @@ def _dispatch(name: str, sig: tuple, call, **annotations):
         _METRICS.counter("backend.jit_compiles", fn=name).incr()
     _METRICS.counter("backend.dispatches", fn=name).incr()
     if not _TRACER.enabled:
-        return call()
+        with _x64():
+            return call()
     with _TRACER.span(
         f"backend.{name}",
         phase="compile" if compiling else "execute",
         **annotations,
-    ):
+    ), _x64():
         return call()
 
 
@@ -184,7 +190,7 @@ def _route_loads_fn(dims: Tuple[int, ...], split_ties: bool):
                 strides.append(acc)
                 acc *= w
             strides = list(reversed(strides))
-            line = jnp.zeros(src.shape[0], dtype=jnp.int64)
+            line = jnp.zeros(src.shape[0], dtype=jnp.int32)
             pos_i = 0
             for j in range(D):
                 if j == k:
@@ -270,7 +276,7 @@ def xla_route_loads(
     return _dispatch(
         "route_loads",
         ("route_loads", dims, bool(split_ties), Mp),
-        lambda: np.asarray(fn(src, dst, vol)),
+        lambda: np.asarray(fn(src.astype(np.int32), dst.astype(np.int32), vol)),
         messages=M,
         bucket=Mp,
     )
@@ -347,13 +353,15 @@ def prepare_drain(paths, link_bw: float = 1.0, double_link_on_2: bool = True) ->
         fl[fi2, pos2] = li2
     has_links = np.zeros(F, dtype=bool)
     has_links[flow] = True
+    with _x64():
+        lf, fl, cap = jnp.asarray(lf), jnp.asarray(fl), jnp.asarray(cap)
     return DrainPlan(
         dims=paths.dims,
         n_flows=F,
         n_links_used=Lu,
-        lf=jnp.asarray(lf),
-        fl=jnp.asarray(fl),
-        cap=jnp.asarray(cap),
+        lf=lf,
+        fl=fl,
+        cap=cap,
         has_links=has_links,
         vol=np.asarray(paths.vol, dtype=np.float64),
         max_iters=Lu + 1,
@@ -530,7 +538,7 @@ def _score_fn(dims: Tuple[int, ...], split_ties: bool, double_link_on_2: bool):
                 strides.append(acc)
                 acc *= w
             strides = list(reversed(strides))
-            line = jnp.zeros(rsrc.shape[0], dtype=jnp.int64)
+            line = jnp.zeros(rsrc.shape[0], dtype=jnp.int32)
             pos_i = 0
             for j in range(D):
                 if j == k:
@@ -547,10 +555,12 @@ def _score_fn(dims: Tuple[int, ...], split_ties: bool, double_link_on_2: bool):
             wm = jnp.where(~fwd, v1, 0.0)
             if split_ties:
                 wm = wm + jnp.where(tie, vol * 0.5, 0.0)
-            pos = jnp.arange(a)
+            pos = jnp.arange(a, dtype=jnp.int32)
             covp = ((pos[None, :] - s[:, None]) % a) < hops[:, None]
             covm = ((pos[None, :] - bstart[:, None]) % a) < hops[:, None]
-            onehot = (line[:, None] == jnp.arange(n_lines)[None, :]).astype(jnp.float64)
+            onehot = (line[:, None] == jnp.arange(n_lines, dtype=jnp.int32)[None, :]).astype(
+                jnp.float64
+            )
             pp = onehot.T @ (wp[:, None] * covp)
             pm = onehot.T @ (wm[:, None] * covm)
             scale = 0.5 if (a == 2 and double_link_on_2) else 1.0
@@ -621,25 +631,49 @@ def score_candidates(
             coords.shape[1],
             int(rsrc.shape[0]),
         ),
-        lambda: fn(coords, rsrc, rdst, vol),
+        lambda: fn(
+            coords.astype(np.int32), rsrc.astype(np.int32), rdst.astype(np.int32), vol
+        ),
         candidates=B,
     )
     return np.asarray(cong), np.asarray(dil)
 
 
 # ---------------------------------------------------------------------------
-# (3) FFT contention cross-correlation.
+# (3) Contention cross-correlation — exact, on the integer matmul path.
 # ---------------------------------------------------------------------------
 @lru_cache(maxsize=64)
-def _contention_fn(D: int):
+def _contention_fn(dims: Tuple[int, ...], n_chunks: int):
+    """Jitted direct circular cross-correlation for one machine (longest
+    axis first) and one chunk count.  Inputs are int8: the 0/1 mask planes
+    ``(P, a, n_rest)`` and the 7-bit chunks of the integer load planes
+    ``(P, n_chunks * a, n_rest)``; the output is the exact int64 field
+    ``(a, n_rest)``."""
     jax, jnp = _jax()
-    axes = tuple(range(2, 2 + D))
+    a, rest = dims[0], dims[1:]
+    n_rest = volume(rest) if rest else 1
 
-    def fn(mask, J):
-        FM = jnp.fft.fftn(mask, axes=axes)
-        FJ = jnp.fft.fftn(J, axes=axes)
-        corr = jnp.fft.ifftn(FM * jnp.conj(FJ), axes=axes)
-        return jnp.maximum(jnp.real(corr).sum(axis=(0, 1)), 0.0)
+    def fn(mask, chunks):
+        # idx[o', u'] = flat index of (u' + o') mod rest, the torus shift
+        # of the remaining axes.
+        o = jnp.arange(n_rest, dtype=jnp.int32)[:, None]
+        u = jnp.arange(n_rest, dtype=jnp.int32)[None, :]
+        idx = jnp.zeros((n_rest, n_rest), jnp.int32)
+        stride = 1
+        for r in reversed(rest):
+            idx = idx + (((o // stride) % r + (u // stride) % r) % r) * stride
+            stride *= r
+        shifted = mask[:, :, idx]  # (P, a, o', u'): mask[p, w, u' + o']
+        # q[p, o', w, (c, u1)] = sum_u' mask[p, w, u' + o'] * chunk_c[p, u1, u']
+        # — int8 x int8 products summed in int32 (<= 127 * n_rest terms).
+        q = jnp.einsum(
+            "pwxu,pvu->pxwv", shifted, chunks, preferred_element_type=jnp.int32
+        ).reshape(mask.shape[0], n_rest, a, n_chunks, a)
+        o1 = jnp.arange(a, dtype=jnp.int32)[:, None]
+        u1 = jnp.arange(a, dtype=jnp.int32)[None, :]
+        g = q[:, :, (o1 + u1) % a, :, jnp.broadcast_to(u1, (a, a))]  # (o1, u1, p, o', c)
+        weights = jnp.asarray([128**c for c in range(n_chunks)], jnp.int64)
+        return (g.astype(jnp.int64) * weights).sum(axis=(1, 2, 4))
 
     return jax.jit(fn)
 
@@ -648,21 +682,40 @@ def xla_contention_field(
     dims: Sequence[int], oriented: Sequence[int], mask: np.ndarray
 ) -> np.ndarray:
     """XLA port of :func:`repro.network.placement.contention_field`: the
-    predicted interference of one orientation at every torus offset, as
-    one batched FFT cross-correlation over all (dimension, direction)
-    load planes.  Values agree with the NumPy engine to FFT round-off
-    (~1e-12) — both sides rank with a 9-decimal rounding, so placement
-    choices are identical."""
-    dims = tuple(int(a) for a in dims)
-    from .placement import base_loads
+    predicted interference of one orientation at every torus offset.
 
-    J = base_loads(dims, tuple(int(w) for w in oriented))
-    fn = _contention_fn(len(dims))
-    return _dispatch(
+    The job's integer load field (:func:`~repro.network.placement.int_base_loads`)
+    is split into 7-bit chunks and correlated with the 0/1 mask planes as
+    one batched int8 matmul with int32 accumulation, so the field is an
+    exact integer — no FFT, no float64, nothing the chip's compiler
+    refuses — divided by ``2n`` on the host.  Equal to the NumPy engine's
+    field bit for bit, so placement choices are identical.  Work is
+    ``N**2 / dims[k]`` multiply-adds per plane (``k`` the longest axis).
+    """
+    from .placement import int_base_loads
+
+    dims = tuple(int(a) for a in dims)
+    oriented = tuple(int(w) for w in oriented)
+    D = len(dims)
+    J = int_base_loads(dims, oriented)
+    k = int(np.argmax(dims))
+    order = (k,) + tuple(j for j in range(D) if j != k)
+    lead = (2 * D, dims[k], -1)
+    loads = np.moveaxis(J, 2 + k, 2).reshape(lead)
+    planes = np.moveaxis(np.asarray(mask, dtype=bool), 2 + k, 2).reshape(lead)
+    n_chunks = -(-max(int(J.max()).bit_length(), 1) // 7)
+    chunks = np.concatenate(
+        [(loads >> (7 * c)) & 127 for c in range(n_chunks)], axis=1
+    ).astype(np.int8)
+    moved = tuple(dims[j] for j in order)
+    fn = _contention_fn(moved, n_chunks)
+    field = _dispatch(
         "contention_field",
-        ("contention_field", dims),
-        lambda: np.asarray(fn(np.asarray(mask, dtype=np.float64), J)),
+        ("contention_field", moved, n_chunks),
+        lambda: np.asarray(fn(planes.astype(np.int8), chunks)),
     )
+    field = np.moveaxis(field.reshape(moved), 0, k)
+    return field / (2 * volume(oriented))
 
 
 # ---------------------------------------------------------------------------
@@ -676,8 +729,15 @@ def _cut_fn():
     if _CUT is None:
         jax, jnp = _jax()
 
-        def fn(S, av, two_t):
-            return jnp.where(S == av[None, :], 0, two_t // S).sum(axis=1)
+        def fn(S, av):
+            # 2t / S_k is twice the product of the other sides: no integer
+            # division, which 64-bit TPU arithmetic can only emulate
+            D = S.shape[1]
+            cut = jnp.zeros(S.shape[0], S.dtype)
+            for k in range(D):
+                others = jnp.prod(S[:, [j for j in range(D) if j != k]], axis=1)
+                cut = cut + jnp.where(S[:, k] == av[k], 0, 2 * others)
+            return cut
 
         _CUT = jax.jit(fn)
     return _CUT
@@ -688,10 +748,12 @@ def xla_cut_scores(dims: Sequence[int], assignments: np.ndarray, t: int) -> np.n
     for each aligned side assignment ``S`` of a volume-``t`` cuboid, the
     exact cut ``sum_k (0 if S_k == dims_k else 2t / S_k)`` — int64
     arithmetic under x64, so the scores equal the NumPy engine's
-    **exactly**."""
+    **exactly**.  Every row's sides must multiply to ``t``."""
     av = np.asarray(tuple(int(a) for a in dims), dtype=np.int64)
     S = np.asarray(assignments, dtype=np.int64)
     if S.shape[0] == 0:
         return np.zeros(0, dtype=np.int64)
-    out = _cut_fn()(S, av, np.int64(2 * int(t)))
-    return np.asarray(out, dtype=np.int64)
+    if not (S.prod(axis=1) == int(t)).all():
+        raise ValueError(f"every assignment's sides must multiply to t={t}")
+    with _x64():
+        return np.asarray(_cut_fn()(S, av), dtype=np.int64)

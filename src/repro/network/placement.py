@@ -56,7 +56,7 @@ import numpy as np
 
 from ..obs.trace import TRACER as _TRACER
 from .backend import resolve_backend
-from .geometry import Geometry, bisection_links, canonical
+from .geometry import Geometry, bisection_links, canonical, volume
 
 Coord = Tuple[int, ...]
 
@@ -452,14 +452,15 @@ def contention_field(
 
     O(D * N log N) for all N offsets — the same trick that finds the free
     translates, applied to the score.  A candidate's own cells are free in
-    the pre-commit grid, so its internal links never self-count.  Values
-    carry FFT round-off (~1e-12); rank with a tolerance
-    (:func:`best_placement` rounds to 9 decimals).
+    the pre-commit grid, so its internal links never self-count.  The
+    correlation runs on the integer field :func:`int_base_loads`, so every
+    value is an integer that the FFT's round-off (far below 1/2) cannot
+    move: it is rounded back exactly, and the field returned is that exact
+    integer over ``2n``.
 
-    ``backend="xla"`` computes all (dimension, direction) planes in one
-    compiled batched FFT (``mask_ffts`` is ignored there — the compiled
-    path transforms the mask in the same call); both backends agree to
-    FFT round-off, below the 9-decimal ranking tolerance.
+    ``backend="xla"`` computes the same exact field as a direct integer
+    correlation on the compiled path (``mask_ffts`` is ignored there); the
+    two backends agree bit for bit.
     """
     dims = tuple(int(a) for a in dims)
     if resolve_backend(backend) == "xla":
@@ -468,7 +469,7 @@ def contention_field(
         return xla_contention_field(dims, tuple(oriented), mask)
     if mask_ffts is None:
         mask_ffts = _mask_plane_ffts(mask)
-    J = base_loads(dims, tuple(oriented))
+    J = int_base_loads(dims, tuple(oriented))
     out = np.zeros(dims, dtype=np.float64)
     for k in range(len(dims)):
         for d in range(2):
@@ -478,7 +479,7 @@ def contention_field(
                 continue
             corr = np.fft.ifftn(F * np.conj(np.fft.fftn(plane)))
             out += np.real(corr)
-    return np.maximum(out, 0.0)
+    return np.rint(out).astype(np.int64) / (2 * volume(tuple(oriented)))
 
 
 def best_placement(

@@ -1,14 +1,18 @@
 """Computation-environment configuration for the jax-backed layers.
 
-The compiled network backends (:mod:`repro.network.backend`) and the
-kernel/roofline layers share two environment concerns:
+The compiled network backends (:mod:`repro.network.backend`), the
+launchers and the kernel/roofline layers share two environment concerns:
 
-* **Precision** — the network engines are exact in float64/int64, so any
-  jit-compiled port must run under ``jax_enable_x64``; a silent fall back
-  to float32 would turn exact link-load identities into approximations.
+* **Compile cache** — :func:`enable_compile_cache` keeps JAX's persistent
+  compilation cache at a stable path, so a later process finds what an
+  earlier one compiled.
 * **Topology** — tests and benchmarks sometimes want a specific platform
   (``cpu``) or a multi-device host (``--xla_force_host_platform_device_count``)
   regardless of what hardware jax detects.
+
+Precision is not set here: the network backends scope 64-bit types to
+their own calls (``jax.enable_x64``), so model code in the same process
+keeps its 32-bit defaults.
 
 All helpers degrade gracefully: importing this module never imports jax,
 and each setter raises ``RuntimeError`` with a clear message when jax is
@@ -22,6 +26,10 @@ from __future__ import annotations
 
 import importlib.util
 import os
+from pathlib import Path
+
+# src/repro/utils/env.py -> the checkout root
+_REPO_ROOT = Path(__file__).resolve().parents[3]
 
 
 def have_jax() -> bool:
@@ -40,19 +48,31 @@ def _require_jax():
     return jax
 
 
-def jax_enable_x64(enable: bool = True) -> None:
-    """Set jax's default array precision to 64-bit (or back to 32).
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives for this checkout.
 
-    With ``enable=False`` the ``JAX_ENABLE_X64`` environment variable is
-    consulted before switching off, matching the upstream convention that
-    the environment wins over a programmatic opt-out.  The flag is
-    process-global; the compiled network backends call this on first use
-    because their exactness contracts (integer link loads, int64 cut
-    arithmetic) require 64-bit types.
+    ``JAX_COMPILATION_CACHE_DIR`` wins when it is set; otherwise the cache
+    sits at one fixed path inside the checkout (``<repo>/.jax_cache``).
+    The path is part of every entry's key, so it never depends on a
+    temporary directory, a process id or the time.
     """
-    if not enable:
-        enable = bool(os.getenv("JAX_ENABLE_X64", 0))
-    _require_jax().config.update("jax_enable_x64", bool(enable))
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(_REPO_ROOT / ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache at
+    :func:`compile_cache_dir` and return the directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it, so
+    nothing is set here; otherwise only ``jax_compilation_cache_dir`` is
+    updated.  Call before the first compile (the launchers and
+    ``chip_smoke.py`` do, at start-up).
+    """
+    jax = _require_jax()
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def set_platform(platform: str = "cpu") -> None:

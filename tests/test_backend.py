@@ -6,6 +6,7 @@ Pins the tentpole's exactness contracts:
     numpy simulate_flows     ~=  xla drain            (<= 1e-9 rel rates)
     sequential score_mapping ==  batched score_candidates   (row-exact)
     numpy cut_table          ==  xla cut_table        (int64-exact)
+    numpy contention_field   ==  xla contention_field (bit-exact)
 
 plus the dispatch machinery (env variable, explicit argument, error
 paths) and the golden Mira / JUQUEEN partition parity the acceptance
@@ -242,6 +243,28 @@ def test_cut_table_backend_parity(dims, t):
     t_x = cut_table(dims, t, backend="xla")
     assert t_np.items() == t_x.items()
     assert t_x.cuts.dtype == np.int64
+
+
+# ---------------------------------------------------------------------------
+# Contention field: exact integer correlation on both backends.
+# ---------------------------------------------------------------------------
+@needs_jax
+@settings(max_examples=8, deadline=None)
+@given(seed=st.integers(0, 2**31 - 1))
+def test_contention_field_backend_parity(seed):
+    from repro.network.allocation import MachineState
+    from repro.network.placement import contention_field, interference_mask
+
+    rng = np.random.default_rng(seed)
+    dims = tuple(int(rng.integers(2, 8)) for _ in range(int(rng.integers(1, 4))))
+    m = MachineState(dims)
+    for job in range(3):
+        m.allocate(job, tuple(int(rng.integers(1, d + 1)) for d in dims))
+    mask = interference_mask(m.grid, m.traffic_loads())
+    oriented = tuple(int(rng.integers(1, d + 1)) for d in dims)
+    f_np = contention_field(dims, oriented, mask)
+    f_x = contention_field(dims, oriented, mask, backend="xla")
+    assert f_x.dtype == np.float64 and np.array_equal(f_np, f_x)
 
 
 # ---------------------------------------------------------------------------
