@@ -49,6 +49,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs.trace import TRACER as _TRACER
 from .fabric import HyperXFabric, TorusFabric
 from .geometry import Geometry, bisection_links, canonical, sub_cuboids
 from .isoperimetry import ranked_geometries, scaled_node_dims
@@ -372,10 +373,19 @@ class AllocationPolicy:
         raise NotImplementedError
 
     def preferences_for(self, machine: MachineState, request: JobRequest) -> List[Geometry]:
-        """Request-aware preference list (hinted policies override)."""
-        return _honor_requested_geometry(
-            self.geometry_preferences(machine, request.units), request
-        )
+        """Request-aware preference list: the policy's ranking with the
+        request's own geometry first.  Traced as ``allocation.rank``."""
+        if _TRACER.enabled:
+            with _TRACER.span("allocation.rank", units=request.units):
+                return _honor_requested_geometry(
+                    self._ranked(machine, request), request
+                )
+        return _honor_requested_geometry(self._ranked(machine, request), request)
+
+    def _ranked(self, machine: MachineState, request: JobRequest) -> List[Geometry]:
+        """The policy's geometry ranking for one request (hinted policies
+        override)."""
+        return self.geometry_preferences(machine, request.units)
 
     def allocate(self, machine: MachineState, request: JobRequest) -> Optional[Placement]:
         """Place the request on the machine, or return None.  Default:
@@ -443,12 +453,9 @@ class HintedPolicy(AllocationPolicy):
         pol = self.iso if contention_bound else self.any
         return pol.geometry_preferences(machine, units)
 
-    def preferences_for(self, machine: MachineState, request: JobRequest) -> List[Geometry]:
-        return _honor_requested_geometry(
-            self.geometry_preferences(
-                machine, request.units, request.contention_bound
-            ),
-            request,
+    def _ranked(self, machine: MachineState, request: JobRequest) -> List[Geometry]:
+        return self.geometry_preferences(
+            machine, request.units, request.contention_bound
         )
 
 

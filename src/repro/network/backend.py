@@ -145,11 +145,22 @@ def _next_pow2(n: int) -> int:
 _JIT_SIGNATURES: set = set()
 
 
+def _to_host(out):
+    """A dispatch's device array (or tuple of them) as NumPy: the blocking
+    device-to-host fetch."""
+    if isinstance(out, tuple):
+        return tuple(np.asarray(o) for o in out)
+    return np.asarray(out)
+
+
 def _dispatch(name: str, sig: tuple, call, **annotations):
-    """Run one compiled-backend dispatch with telemetry: jit-compile /
-    dispatch counters in :data:`repro.obs.REGISTRY` (always on — one dict
-    update per coarse call) and a ``backend.<name>`` span with the
-    compile-vs-execute phase when tracing is enabled."""
+    """Run one compiled-backend dispatch and fetch its result to the host,
+    with telemetry: jit-compile / dispatch counters in
+    :data:`repro.obs.REGISTRY` (always on — one dict update per coarse
+    call) and, when tracing is enabled, a ``backend.<name>`` span with the
+    compile-vs-execute phase holding two children: ``xla.call`` (entering
+    x64 and the jitted call returning device arrays) and ``xla.fetch``
+    (the blocking conversion to NumPy)."""
     compiling = sig not in _JIT_SIGNATURES
     if compiling:
         _JIT_SIGNATURES.add(sig)
@@ -157,13 +168,17 @@ def _dispatch(name: str, sig: tuple, call, **annotations):
     _METRICS.counter("backend.dispatches", fn=name).incr()
     if not _TRACER.enabled:
         with _x64():
-            return call()
+            out = call()
+        return _to_host(out)
     with _TRACER.span(
         f"backend.{name}",
         phase="compile" if compiling else "execute",
         **annotations,
-    ), _x64():
-        return call()
+    ):
+        with _TRACER.span("xla.call"), _x64():
+            out = call()
+        with _TRACER.span("xla.fetch"):
+            return _to_host(out)
 
 
 # ---------------------------------------------------------------------------
@@ -276,7 +291,7 @@ def xla_route_loads(
     return _dispatch(
         "route_loads",
         ("route_loads", dims, bool(split_ties), Mp),
-        lambda: np.asarray(fn(src.astype(np.int32), dst.astype(np.int32), vol)),
+        lambda: fn(src.astype(np.int32), dst.astype(np.int32), vol),
         messages=M,
         bucket=Mp,
     )
@@ -479,7 +494,7 @@ def drain(
     )
     if bool(unfinished):
         raise RuntimeError(f"flow simulation exceeded {max_steps} steps")
-    return np.asarray(fc), int(steps)
+    return fc, int(steps)
 
 
 def drain_batch(
@@ -636,7 +651,7 @@ def score_candidates(
         ),
         candidates=B,
     )
-    return np.asarray(cong), np.asarray(dil)
+    return cong, dil
 
 
 # ---------------------------------------------------------------------------
@@ -712,7 +727,7 @@ def xla_contention_field(
     field = _dispatch(
         "contention_field",
         ("contention_field", moved, n_chunks),
-        lambda: np.asarray(fn(planes.astype(np.int8), chunks)),
+        lambda: fn(planes.astype(np.int8), chunks),
     )
     field = np.moveaxis(field.reshape(moved), 0, k)
     return field / (2 * volume(oriented))

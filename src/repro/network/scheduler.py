@@ -173,6 +173,20 @@ class _Live:
     priority: int
 
 
+class _CountingFit:
+    """:func:`first_fit` that counts its calls (the traced reservation
+    scan's ``probes``)."""
+
+    __slots__ = ("calls",)
+
+    def __init__(self) -> None:
+        self.calls = 0
+
+    def __call__(self, grid: np.ndarray, geometry: Geometry):
+        self.calls += 1
+        return first_fit(grid, geometry)
+
+
 class SchedulerService:
     """Event-sourced online scheduler wrapping one
     :class:`~repro.network.allocation.MachineState`.
@@ -500,7 +514,17 @@ class SchedulerService:
                     self._waiting.pop(0)
                     continue
                 prefs = self.policy.preferences_for(self.machine, head.request)
-                t_res = self._reservation(prefs)
+                if _TRACER.enabled:
+                    with _TRACER.span(
+                        "scheduler.reserve",
+                        job=head.request.job_id,
+                        units=head.request.units,
+                    ) as _sp:
+                        probe = _CountingFit()
+                        t_res = self._reservation(prefs, probe)
+                        _sp.annotate(probes=probe.calls)
+                else:
+                    t_res = self._reservation(prefs)
                 if t_res is None:
                     self._log(
                         REJECT, job_id=head.request.job_id, reason="impossible"
@@ -510,15 +534,24 @@ class SchedulerService:
                     continue
                 self._blocked = (head.request.job_id, t_res)
             if self.backfill:
-                kept: List[_Queued] = []
-                for queued in self._waiting[1:]:
-                    if not (
-                        time_le(self.now + queued.request.duration, t_res)
-                        and self._try_start(queued)
-                    ):
-                        kept.append(queued)
-                self._waiting[1:] = kept
+                if _TRACER.enabled:
+                    with _TRACER.span("scheduler.backfill"):
+                        self._backfill(t_res)
+                else:
+                    self._backfill(t_res)
             break
+
+    def _backfill(self, t_res: float) -> None:
+        """EASY backfill behind the blocked head: start every later job
+        that fits now and ends by the head's reservation ``t_res``."""
+        kept: List[_Queued] = []
+        for queued in self._waiting[1:]:
+            if not (
+                time_le(self.now + queued.request.duration, t_res)
+                and self._try_start(queued)
+            ):
+                kept.append(queued)
+        self._waiting[1:] = kept
 
     def _try_start(self, queued: _Queued) -> bool:
         request = queued.request
@@ -598,13 +631,16 @@ class SchedulerService:
                 return True
         return False  # pragma: no cover - the scratch check guarantees a fit
 
-    def _reservation(self, prefs: List[Geometry]) -> Optional[float]:
+    def _reservation(
+        self, prefs: List[Geometry], fit=first_fit
+    ) -> Optional[float]:
         """Earliest time the blocked head is guaranteed to fit: replay
         every pending free — running jobs' completions *and* scheduled
         repairs of failed cells — on a scratch grid in time order until a
         preferred geometry fits.  None: never fits, not even with every
         pending free applied — the request is impossible on the (possibly
-        degraded) machine."""
+        degraded) machine.  ``fit`` is the placement probe (the traced
+        path passes a counting one)."""
         if not prefs:
             return None
         frees: List[Tuple[float, int, object]] = []
@@ -623,9 +659,9 @@ class SchedulerService:
                 for cell in freed:
                     if tuple(cell) in self.failed_cells:
                         scratch[tuple(cell)] = False
-            if any(first_fit(scratch, g) is not None for g in prefs):
+            if any(fit(scratch, g) is not None for g in prefs):
                 return time
-        if any(first_fit(scratch, g) is not None for g in prefs):
+        if any(fit(scratch, g) is not None for g in prefs):
             return self.now  # defensive: only asked after a failed allocate
         return None
 

@@ -7,11 +7,16 @@ and contention attribution"):
   **off by default** with a near-zero disabled path (gated <= 2%
   overhead in ``BENCH_obs.json``), exporting Chrome trace-event JSON.
   Instrumented boundaries: scheduler event processing
-  (``scheduler.step`` / ``scheduler.place``), placement search
-  (``placement.search``), netsim draining (``netsim.drain``), backend
-  dispatch (``backend.*`` with jit recompile / padding-bucket counters
-  and a compile-vs-execute split), planner candidate pricing
-  (``planner.price``), and the launch drivers' wall-clock timers.
+  (``scheduler.step`` / ``scheduler.place``), the blocked head's
+  reservation scan and backfill (``scheduler.reserve`` /
+  ``scheduler.backfill``), geometry ranking (``allocation.rank``),
+  placement search (``placement.search``), netsim draining
+  (``netsim.drain``), backend dispatch (``backend.*`` with jit recompile
+  / padding-bucket counters, a compile-vs-execute split, and
+  ``xla.call`` / ``xla.fetch`` children), planner candidate pricing
+  (``planner.price``), and the launch drivers' wall-clock timers.  With
+  jax imported, each span is also a profiler ``TraceAnnotation``
+  (``scheduler:reserve``), on the profiler's clock.
 * :mod:`repro.obs.metrics` — a registry of counters / gauges /
   histograms with labeled series and JSON snapshot export;
   :func:`scheduler_metrics` derives the scheduler's queue-depth /

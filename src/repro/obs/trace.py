@@ -12,7 +12,15 @@ Perfetto / ``chrome://tracing``.
 Tracing is **globally off by default** and the disabled path is near
 zero: ``TRACER.span(...)`` returns a shared no-op context manager after
 one attribute check, and the hot call sites additionally guard on
-``TRACER.enabled`` so no argument dict is even built.  Enabling tracing
+``TRACER.enabled`` so no argument dict is even built.
+
+While tracing is on and ``jax`` is already imported, every recorded span
+is also emitted as a profiler ``TraceMe`` (``jax.profiler.TraceAnnotation``)
+named ``layer:what`` — the span's name with its first ``.`` turned into
+``:`` (``scheduler.reserve`` -> ``scheduler:reserve``).  Under
+``jax.profiler.start_trace`` the spans then sit in the host plane of the
+same profile as the device's operations, on the profiler's clock.  This
+module never imports jax itself.  Enabling tracing
 never perturbs results — spans only *measure*; the scheduler event log,
 netsim makespans, and planner tables are bit-identical either way
 (pinned in ``tests/test_obs.py``, overhead gated in ``BENCH_obs.json``).
@@ -29,6 +37,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -43,7 +52,7 @@ class Span:
     and :meth:`incr` accumulates additive counters — both land in the
     exported event's ``args``."""
 
-    __slots__ = ("name", "args", "tid", "_tracer", "_t0", "duration")
+    __slots__ = ("name", "args", "tid", "_tracer", "_t0", "_traceme", "duration")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -51,6 +60,7 @@ class Span:
         self.args = args
         self.tid = threading.get_ident()
         self._t0 = 0
+        self._traceme = None
         self.duration = 0.0  # seconds, set at exit
 
     def annotate(self, **kv: Any) -> "Span":
@@ -64,6 +74,7 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        self._traceme = self._tracer._enter_profiler(self.name)
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -71,6 +82,8 @@ class Span:
         t1 = time.perf_counter_ns()
         self.duration = (t1 - self._t0) * 1e-9
         self._tracer._record(self, self._t0, t1)
+        if self._traceme is not None:
+            self._traceme.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -106,7 +119,7 @@ class Timer:
     *also* recorded as a span — so driver wall-clock numbers land in the
     same trace stream as the engine spans."""
 
-    __slots__ = ("name", "args", "elapsed", "_tracer", "_t0")
+    __slots__ = ("name", "args", "elapsed", "_tracer", "_t0", "_traceme")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -114,6 +127,7 @@ class Timer:
         self.args = args
         self.elapsed = 0.0
         self._t0 = 0
+        self._traceme = None
 
     def annotate(self, **kv: Any) -> "Timer":
         """Attach key/value annotations (recorded when tracing is on)."""
@@ -121,6 +135,8 @@ class Timer:
         return self
 
     def __enter__(self) -> "Timer":
+        if self._tracer.enabled:
+            self._traceme = self._tracer._enter_profiler(self.name)
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -131,6 +147,9 @@ class Timer:
             span = Span(self._tracer, self.name, self.args)
             span.duration = self.elapsed
             self._tracer._record(span, self._t0, t1)
+        if self._traceme is not None:
+            self._traceme.__exit__(exc_type, exc, tb)
+            self._traceme = None
         return False
 
 
@@ -148,6 +167,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._events: List[Dict[str, Any]] = []
         self._epoch = time.perf_counter_ns()
+        self._annotation = None  # jax.profiler.TraceAnnotation, once jax is imported
 
     # -- control ------------------------------------------------------------
     def enable(self, clear: bool = False) -> None:
@@ -179,6 +199,20 @@ class Tracer:
         """An always-measuring :class:`Timer` (span recorded only when
         tracing is enabled)."""
         return Timer(self, name, args)
+
+    def _enter_profiler(self, name: str):
+        """Enter the profiler annotation of a span about to start (None
+        until ``jax`` has been imported by someone else)."""
+        annotation = self._annotation
+        if annotation is None:
+            if "jax" not in sys.modules:
+                return None
+            from jax.profiler import TraceAnnotation
+
+            annotation = self._annotation = TraceAnnotation
+        traceme = annotation(name.replace(".", ":", 1))
+        traceme.__enter__()
+        return traceme
 
     def _record(self, span: Span, t0_ns: int, t1_ns: int) -> None:
         event = {

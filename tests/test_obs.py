@@ -213,6 +213,226 @@ def test_export_chrome_trace_to_file(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# The layers below scheduler.step: reservation scan, backfill, geometry
+# ranking and the backend's call/fetch split, on a Mira-size stream.
+# ---------------------------------------------------------------------------
+NEW_SPANS = ("scheduler.reserve", "scheduler.backfill", "allocation.rank",
+             "xla.call", "xla.fetch")
+
+
+def _blocking_mira_stream():
+    """80 jobs on Mira's (4,4,3,2) midplane torus, arriving fast enough that
+    heads block: 63 reservation scans and 87 backfill tries."""
+    return generate_scenario((4, 4, 3, 2), 80, seed=0, failure_rate=0.01,
+                             burst_gap=8.0)
+
+
+def test_reserve_spans_count_scans_and_probes(monkeypatch):
+    import repro.network.scheduler as sch
+
+    calls = {"reserve": 0, "first_fit": 0}
+    reservation, first_fit = sch.SchedulerService._reservation, sch.first_fit
+
+    def counted_reservation(self, *a, **k):
+        calls["reserve"] += 1
+        return reservation(self, *a, **k)
+
+    def counted_first_fit(*a, **k):
+        calls["first_fit"] += 1
+        return first_fit(*a, **k)
+
+    monkeypatch.setattr(sch.SchedulerService, "_reservation", counted_reservation)
+    monkeypatch.setattr(sch, "first_fit", counted_first_fit)
+    TRACER.enable(clear=True)
+    run_scenario(_blocking_mira_stream(), ContentionScoredPolicy(), backfill=True)
+    TRACER.disable()
+    events = TRACER.events()
+    reserves = [e for e in events if e["name"] == "scheduler.reserve"]
+    assert calls["reserve"] > 20 and len(reserves) == calls["reserve"]
+    assert sum(e["args"]["probes"] for e in reserves) == calls["first_fit"]
+    ranks = [e for e in events if e["name"] == "allocation.rank"]
+    assert ranks and all(e["args"]["units"] >= 1 for e in ranks)
+    _assert_proper_nesting(events)
+
+
+def test_backfill_tries_are_the_place_spans_inside_backfill(monkeypatch):
+    import importlib.util
+    from pathlib import Path
+
+    import repro.network.scheduler as sch
+
+    # count the _try_start calls made by the backfill pass
+    tried = {"n": 0, "in_pass": False}
+    backfill, try_start = sch.SchedulerService._backfill, sch.SchedulerService._try_start
+
+    def counted_backfill(self, t_res):
+        tried["in_pass"] = True
+        try:
+            return backfill(self, t_res)
+        finally:
+            tried["in_pass"] = False
+
+    def counted_try_start(self, queued):
+        tried["n"] += tried["in_pass"]
+        return try_start(self, queued)
+
+    monkeypatch.setattr(sch.SchedulerService, "_backfill", counted_backfill)
+    monkeypatch.setattr(sch.SchedulerService, "_try_start", counted_try_start)
+    TRACER.enable(clear=True)
+    service = run_scenario(_blocking_mira_stream(), ContentionScoredPolicy(),
+                           backfill=True)
+    TRACER.disable()
+    bench = Path(__file__).resolve().parents[1] / "chipbench"
+    monkeypatch.syspath_prepend(str(bench))
+    spec = importlib.util.spec_from_file_location(
+        "reader_backfill_tries", bench / "metrics" / "backfill_tries_per_event.py")
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    per_event = reader.read({"units": [{"log": service.log}],
+                             "spans": TRACER.events()})
+    assert tried["n"] > 0
+    assert per_event * len(service.log) == pytest.approx(tried["n"])
+
+
+def test_scheduler_log_identical_with_tracing_when_heads_block():
+    scenario = _blocking_mira_stream()
+    s_off = run_scenario(scenario, ContentionScoredPolicy(), backfill=True)
+    TRACER.enable(clear=True)
+    s_on = run_scenario(scenario, ContentionScoredPolicy(), backfill=True)
+    TRACER.disable()
+    assert _log_key(s_off) == _log_key(s_on)
+    assert any(e.kind == "start" for e in s_on.log)
+    names = {e["name"] for e in TRACER.events()}
+    assert {"scheduler.reserve", "scheduler.backfill", "allocation.rank"} <= names
+
+
+def _profile(trace_dir, work):
+    """Run ``work`` under jax's profiler (Python tracer off); returns its
+    result and the names of the profiler's host-plane events."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        out = work()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True))[-1]
+    host = [e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events]
+    return out, host
+
+
+def _profiled_replay(trace_dir, traced):
+    """Replay the blocking stream on the xla backend under the profiler;
+    returns the service, the repro.obs spans and the host-plane names."""
+    if traced:
+        TRACER.enable(clear=True)
+    try:
+        service, host = _profile(trace_dir, lambda: run_scenario(
+            _blocking_mira_stream(), ContentionScoredPolicy(), backfill=True,
+            backend="xla"))
+    finally:
+        TRACER.disable()
+    spans = TRACER.events()
+    TRACER.clear()
+    return service, spans, host
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    obs.clear_telemetry()
+    return _profiled_replay(tmp_path_factory.mktemp("profile"), traced=True)
+
+
+def test_spans_land_on_the_profilers_host_plane(profiled, tmp_path):
+    from collections import Counter
+
+    _, spans, host = profiled
+    on_host = Counter(host)
+    recorded = Counter(e["name"] for e in spans)
+    for name in ("scheduler.step", "scheduler.place",
+                 "backend.contention_field") + NEW_SPANS:
+        label = name.replace(".", ":", 1)
+        assert recorded[name] > 0, name
+        assert on_host[label] == recorded[name], name
+    # tracing off: the profiler sees none of the program's spans
+    _, spans_off, host_off = _profiled_replay(tmp_path, traced=False)
+    assert spans_off == []
+    labels = {n.replace(".", ":", 1) for n in recorded}
+    assert not labels & set(host_off)
+
+
+def test_backend_fetch_nests_inside_its_dispatch(profiled):
+    _, spans, _ = profiled
+    dispatches = [e for e in spans if e["name"] == "backend.contention_field"]
+    for child in ("xla.call", "xla.fetch"):
+        kids = [e for e in spans if e["name"] == child]
+        assert len(kids) == len(dispatches)
+        assert all(
+            any(d["ts"] <= k["ts"] and k["ts"] + k["dur"] <= d["ts"] + d["dur"] + 1
+                for d in dispatches)
+            for k in kids
+        )
+
+
+def test_existing_span_readers_ignore_the_new_spans(profiled, monkeypatch):
+    import importlib.util
+    from pathlib import Path
+
+    service, spans, _ = profiled
+    bench = Path(__file__).resolve().parents[1] / "chipbench"
+    monkeypatch.syspath_prepend(str(bench))
+    kept = [e for e in spans if e["name"] not in NEW_SPANS]
+    assert len(kept) < len(spans)
+    for metric in ("scheduler_ms_per_event", "place_ms_per_event",
+                   "backend_ms_per_event"):
+        spec = importlib.util.spec_from_file_location(
+            f"reader_{metric}", bench / "metrics" / f"{metric}.py")
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+        units = [{"log": service.log}]
+        full = reader.read({"units": units, "spans": spans})
+        assert full > 0, metric
+        assert full == reader.read({"units": units, "spans": kept}), metric
+
+
+def test_timers_and_undotted_spans_reach_the_profiler(tmp_path):
+    def work():
+        TRACER.enable(clear=True)
+        with obs.timer("serve.decode.step"), TRACER.span("plain"):
+            pass
+        TRACER.disable()
+        with obs.timer("serve.prefill"):  # tracing off: no annotation
+            pass
+
+    _, host = _profile(tmp_path, work)
+    assert {"serve:decode.step", "plain"} <= set(host)
+    assert "serve:prefill" not in host
+
+
+def test_obs_stays_importable_without_jax():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys, repro.obs as obs\n"
+        "obs.enable_tracing()\n"
+        "with obs.trace('scheduler.step'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules\n"
+        "assert [e['name'] for e in obs.export_chrome_trace()['traceEvents']]"
+        " == ['scheduler.step']\n"
+    )
+    subprocess.run([sys.executable, "-c", code], check=True)
+
+
+# ---------------------------------------------------------------------------
 # Metrics registry + scheduler metrics.
 # ---------------------------------------------------------------------------
 def test_registry_basics():
