@@ -37,8 +37,7 @@ The queue simulator is event-driven: jobs carry ``arrival`` timestamps,
 head-of-line blocking is FCFS-exact, and ``backfill=True`` enables
 EASY-style conservative backfill — a later job may jump the blocked head
 only if it terminates before the head's reservation (the earliest time the
-head is guaranteed to fit, computed by replaying completions on a scratch
-grid).
+head is guaranteed to fit, found by a search over the pending frees).
 """
 
 from __future__ import annotations

@@ -175,6 +175,76 @@ def first_fit(
     return None
 
 
+# Largest packed cuboid table, in bytes before deduplication (orientations
+# x offsets x ceil(N / 8)), that :class:`FitTest` builds for one geometry:
+# every geometry of Mira's 96-midplane torus or JUQUEEN's (7, 2, 2, 2) fits
+# far inside it; a node-level torus does not and keeps :func:`first_fit`.
+_PACKED_TABLE_BUDGET = 4 << 20
+
+
+@lru_cache(maxsize=64)
+def _packed_cuboids(dims: Geometry, geometry: Geometry) -> Optional[np.ndarray]:
+    """Every placement :func:`first_fit` may find for ``geometry`` as a
+    packed cell mask: one ``uint8`` row of ``ceil(N / 8)`` bytes (the C-order
+    flattened grid, :func:`numpy.packbits` order) per distinct cell set over
+    all orientations and torus offsets.  None where the table would pass
+    the budget.  Memoised — callers must not mutate the result."""
+    perms = orientations(geometry, dims)
+    n = volume(dims)
+    if len(perms) * n * -(-n // 8) > _PACKED_TABLE_BUDGET:
+        return None
+    rows = [np.zeros((0, -(-n // 8)), dtype=np.uint8)]
+    for perm in perms:
+        # Offset o covers cell c iff (c_k - o_k) mod a_k < w_k in every dim:
+        # the (offsets, cells) mask is the Kronecker product of one such
+        # (a_k, a_k) matrix per dim, rows and columns both in C order.
+        mask = np.ones((1, 1), dtype=np.uint8)
+        for w, a in zip(perm, dims):
+            ring = np.arange(a)
+            covers = np.subtract.outer(ring, ring).T % a < w  # [o, c]
+            mask = np.kron(mask, covers.astype(np.uint8))
+        rows.append(np.packbits(mask, axis=1))
+    table = np.unique(np.concatenate(rows), axis=0)
+    table.setflags(write=False)
+    return table
+
+
+class FitTest:
+    """Whether some geometry of a list fits on an occupancy grid:
+    ``FitTest(dims, geometries)(grid)`` is
+    ``any(first_fit(grid, g) is not None for g in geometries)``.
+
+    Where the machine is small enough, each geometry's placements are a
+    memoised table of packed cell masks, and all of them are tested in one
+    vectorised ``&`` with the packed grid (a fit is a row with no occupied
+    bit); the geometries whose table would pass the budget, on larger
+    machines, go through :func:`first_fit`.  ``packed`` says whether the
+    table path runs."""
+
+    __slots__ = ("rows", "loose")
+
+    def __init__(self, dims: Sequence[int], geometries: Sequence[Sequence[int]]):
+        dims = tuple(int(a) for a in dims)
+        tables, self.loose = [], []
+        for g in geometries:
+            table = _packed_cuboids(dims, pad_geometry(g, len(dims)))
+            if table is None:
+                self.loose.append(g)
+            else:
+                tables.append(table)
+        self.rows = np.concatenate(tables) if tables else None
+
+    @property
+    def packed(self) -> bool:
+        return self.rows is not None
+
+    def __call__(self, grid: np.ndarray) -> bool:
+        if self.rows is not None:
+            if not (self.rows & np.packbits(grid)).any(axis=1).all():
+                return True
+        return any(first_fit(grid, g) is not None for g in self.loose)
+
+
 def placement_cells(
     dims: Sequence[int], oriented: Sequence[int], offset: Coord
 ) -> Tuple[np.ndarray, ...]:

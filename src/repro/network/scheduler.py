@@ -82,7 +82,7 @@ from .allocation import (
 from .fabric import HyperXFabric
 from .geometry import Geometry
 from .isoperimetry import best_bisection_geometry, scaled_node_dims
-from .placement import first_fit, placement_cells
+from .placement import FitTest, first_fit, placement_cells
 from .routing import hyperx_all_to_all_max_load, predict_pairing_time
 
 Coord = Tuple[int, ...]
@@ -173,20 +173,6 @@ class _Live:
     priority: int
 
 
-class _CountingFit:
-    """:func:`first_fit` that counts its calls (the traced reservation
-    scan's ``probes``)."""
-
-    __slots__ = ("calls",)
-
-    def __init__(self) -> None:
-        self.calls = 0
-
-    def __call__(self, grid: np.ndarray, geometry: Geometry):
-        self.calls += 1
-        return first_fit(grid, geometry)
-
-
 class SchedulerService:
     """Event-sourced online scheduler wrapping one
     :class:`~repro.network.allocation.MachineState`.
@@ -194,8 +180,8 @@ class SchedulerService:
     The scheduling pass after each event cluster reproduces the historical
     ``simulate_queue`` loop exactly: the head of the waiting queue is
     tried first (FCFS within a priority level), a blocked head caches its
-    reservation — the earliest time it is guaranteed to fit, by replaying
-    pending frees on a scratch grid — until *any* grid-freeing event
+    reservation — the earliest time it is guaranteed to fit, by a search
+    over the pending frees (:meth:`_reservation`) — until *any* grid-freeing event
     (Complete, Fail, Preempt, priority eviction or a cell repair)
     invalidates it, and with ``backfill=True`` later jobs may jump a
     blocked head only if they finish by the reservation (EASY backfill).
@@ -520,9 +506,7 @@ class SchedulerService:
                         job=head.request.job_id,
                         units=head.request.units,
                     ) as _sp:
-                        probe = _CountingFit()
-                        t_res = self._reservation(prefs, probe)
-                        _sp.annotate(probes=probe.calls)
+                        t_res = self._reservation(prefs, _sp)
                 else:
                     t_res = self._reservation(prefs)
                 if t_res is None:
@@ -631,39 +615,56 @@ class SchedulerService:
                 return True
         return False  # pragma: no cover - the scratch check guarantees a fit
 
-    def _reservation(
-        self, prefs: List[Geometry], fit=first_fit
-    ) -> Optional[float]:
-        """Earliest time the blocked head is guaranteed to fit: replay
-        every pending free — running jobs' completions *and* scheduled
-        repairs of failed cells — on a scratch grid in time order until a
-        preferred geometry fits.  None: never fits, not even with every
-        pending free applied — the request is impossible on the (possibly
-        degraded) machine.  ``fit`` is the placement probe (the traced
-        path passes a counting one)."""
+    def _reservation(self, prefs: List[Geometry], span=None) -> Optional[float]:
+        """Earliest time the blocked head is guaranteed to fit: the time of
+        the first pending free — running jobs' completions *and* scheduled
+        repairs of failed cells, in time order — after which a preferred
+        geometry fits on the grid with every free so far applied.  None:
+        never fits, not even with every pending free applied — the request
+        is impossible on the (possibly degraded) machine.
+
+        Frees only clear cells, so "some preferred geometry fits" is
+        monotone in the free index: a binary search over the cumulative
+        unions of the frees' cells finds the same first free as replaying
+        them one by one, in about log2(frees) tests of a :class:`FitTest`.
+        ``span`` (the traced path's ``scheduler.reserve``) is annotated
+        with ``frees``, ``packed`` and ``probes``, the grids tested."""
         if not prefs:
             return None
+        dims = self.machine.dims
         frees: List[Tuple[float, int, object]] = []
         for live in self._live.values():
             frees.append((live.job.end, live.gen, live.job.placement))
         for time, _, seq, kind, data in self._pending:
             if kind == RECLAIM and data[1]:
                 frees.append((time, seq, tuple(data[1])))
-        scratch = self.machine.grid.copy()
-        for time, _, freed in sorted(frees, key=lambda f: (f[0], f[1])):
+        frees.sort(key=lambda f: (f[0], f[1]))
+        # cleared[i]: the cells the first i + 1 frees clear.  No frees: one
+        # empty row at the current time — the grid itself, tested
+        # defensively (only asked after a failed allocate).
+        cleared = np.zeros((max(len(frees), 1),) + dims, dtype=bool)
+        for i, (_, _, freed) in enumerate(frees):
             if isinstance(freed, Placement):
-                scratch[
-                    placement_cells(self.machine.dims, freed.oriented, freed.offset)
-                ] = False
+                cleared[i][placement_cells(dims, freed.oriented, freed.offset)] = True
             else:
                 for cell in freed:
                     if tuple(cell) in self.failed_cells:
-                        scratch[tuple(cell)] = False
-            if any(fit(scratch, g) is not None for g in prefs):
-                return time
-        if any(fit(scratch, g) is not None for g in prefs):
-            return self.now  # defensive: only asked after a failed allocate
-        return None
+                        cleared[i][tuple(cell)] = True
+        np.logical_or.accumulate(cleared, axis=0, out=cleared)
+        times = [f[0] for f in frees] or [self.now]
+        fits = FitTest(dims, prefs)
+        if span is not None:
+            span.annotate(frees=len(frees), packed=fits.packed, probes=0)
+        lo, hi = 0, len(times)
+        while lo < hi:
+            mid = (lo + hi) // 2
+            if span is not None:
+                span.incr("probes")
+            if fits(self.machine.grid & ~cleared[mid]):
+                hi = mid
+            else:
+                lo = mid + 1
+        return times[lo] if lo < len(times) else None
 
     def _optimal_bisection(self, units: int) -> int:
         if units not in self._opt_bisection:

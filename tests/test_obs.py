@@ -230,26 +230,30 @@ def _blocking_mira_stream():
 def test_reserve_spans_count_scans_and_probes(monkeypatch):
     import repro.network.scheduler as sch
 
-    calls = {"reserve": 0, "first_fit": 0}
-    reservation, first_fit = sch.SchedulerService._reservation, sch.first_fit
+    calls = {"reserve": 0, "grids": 0}
+    reservation, fits = sch.SchedulerService._reservation, sch.FitTest.__call__
 
     def counted_reservation(self, *a, **k):
         calls["reserve"] += 1
         return reservation(self, *a, **k)
 
-    def counted_first_fit(*a, **k):
-        calls["first_fit"] += 1
-        return first_fit(*a, **k)
+    def counted_fits(self, grid):
+        calls["grids"] += 1
+        return fits(self, grid)
 
     monkeypatch.setattr(sch.SchedulerService, "_reservation", counted_reservation)
-    monkeypatch.setattr(sch, "first_fit", counted_first_fit)
+    monkeypatch.setattr(sch.FitTest, "__call__", counted_fits)
     TRACER.enable(clear=True)
     run_scenario(_blocking_mira_stream(), ContentionScoredPolicy(), backfill=True)
     TRACER.disable()
     events = TRACER.events()
     reserves = [e for e in events if e["name"] == "scheduler.reserve"]
     assert calls["reserve"] > 20 and len(reserves) == calls["reserve"]
-    assert sum(e["args"]["probes"] for e in reserves) == calls["first_fit"]
+    # probes: the scratch grids the scan tested, one per search step
+    assert sum(e["args"]["probes"] for e in reserves) == calls["grids"]
+    for e in reserves:
+        assert e["args"]["packed"]  # Mira's 96 midplanes keep the table
+        assert 1 <= e["args"]["probes"] <= max(e["args"]["frees"], 1).bit_length()
     ranks = [e for e in events if e["name"] == "allocation.rank"]
     assert ranks and all(e["args"]["units"] >= 1 for e in ranks)
     _assert_proper_nesting(events)

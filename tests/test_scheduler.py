@@ -5,6 +5,7 @@ determinism (PR 7's tentpole + satellites)."""
 import numpy as np
 import pytest
 
+from repro.core.bgq import MIRA_SCHEDULER_PARTITIONS
 from repro.network import (
     IsoperimetricPolicy,
     JobRequest,
@@ -17,8 +18,17 @@ from repro.network import (
     run_scenario,
     simulate_queue,
 )
-from repro.network.placement import int_base_loads, placement_loads
-from repro.network.scheduler import time_close, time_eps, time_le
+from repro.network.allocation import Placement
+from repro.network.fabric import HyperXFabric
+from repro.network.geometry import canonical, sub_cuboids, volume
+from repro.network.placement import (
+    FitTest,
+    first_fit,
+    int_base_loads,
+    placement_cells,
+    placement_loads,
+)
+from repro.network.scheduler import RECLAIM, time_close, time_eps, time_le
 from repro.runtime.fault_tolerance import HeartbeatMonitor
 
 
@@ -357,3 +367,111 @@ def test_simulate_queue_matches_manual_service(backfill):
         for j in direct.jobs
     ]
     assert res.rejected == direct.rejected
+
+
+# ---------------------------------------------------------------------------
+# The reservation scan: a monotone search over packed occupancy.
+# ---------------------------------------------------------------------------
+def _linear_reservation(svc, prefs):
+    """The reservation scan as a linear replay, kept as the oracle: every
+    pending free applied to a scratch grid in time order, ``first_fit``
+    probed for each preferred geometry after each one."""
+    if not prefs:
+        return None
+    frees = []
+    for live in svc._live.values():
+        frees.append((live.job.end, live.gen, live.job.placement))
+    for time, _, seq, kind, data in svc._pending:
+        if kind == RECLAIM and data[1]:
+            frees.append((time, seq, tuple(data[1])))
+    scratch = svc.machine.grid.copy()
+    for time, _, freed in sorted(frees, key=lambda f: (f[0], f[1])):
+        if isinstance(freed, Placement):
+            scratch[
+                placement_cells(svc.machine.dims, freed.oriented, freed.offset)
+            ] = False
+        else:
+            for cell in freed:
+                if tuple(cell) in svc.failed_cells:
+                    scratch[tuple(cell)] = False
+        if any(first_fit(scratch, g) is not None for g in prefs):
+            return time
+    if any(first_fit(scratch, g) is not None for g in prefs):
+        return svc.now  # defensive: only asked after a failed allocate
+    return None
+
+
+@pytest.mark.parametrize(
+    "machine, packed",
+    [
+        pytest.param((4, 4, 3, 2), True, id="mira"),
+        pytest.param((7, 2, 2, 2), True, id="juqueen"),
+        pytest.param(HyperXFabric((6, 3, 2)), True, id="hyperx-6x3x2"),
+        pytest.param((24, 24, 12), False, id="above-table-budget"),
+    ],
+)
+def test_reservation_search_matches_linear_replay(machine, packed):
+    """Seeded running sets, failures and pending repairs: the searched
+    reservation equals the linear replay's, ``None`` and ``now`` included,
+    on the table path and, above its budget, on ``first_fit``'s."""
+    dims = tuple(machine.dims if isinstance(machine, HyperXFabric) else machine)
+    rng = np.random.default_rng(int(np.prod(dims)))
+
+    def cuboid(cap=None):
+        return canonical(int(rng.integers(1, min(a, cap or a) + 1)) for a in dims)
+
+    # Jobs of at most 6 cells a side keep the big machine's traffic fields cheap.
+    table = {volume(g): g for g in (cuboid(6) for _ in range(8))}
+    svc = SchedulerService(machine, ListPolicy(table), backfill=True)
+    for job_id in range(40):
+        svc.submit(JobRequest(
+            job_id,
+            int(rng.choice(sorted(table))),
+            duration=float(rng.choice([4.0, 8.0, 12.0])),  # tied ends
+            arrival=float(rng.integers(0, 30)),
+        ))
+    cells = [tuple(int(rng.integers(a)) for a in dims) for _ in range(22)]
+    for k, cell in enumerate(cells[:4]):
+        when = float(rng.integers(0, 30))
+        svc.inject_failure(when, [cell])
+        if k:  # the first failed cell is never repaired
+            svc.inject_reclaim(when + 15.0, cells=[cell])
+    # Repairs of cells that have not failed free nothing.
+    spurious = [cell for cell in cells[4:] if cell != cells[0]]
+    for k in range(0, len(spurious), 3):
+        svc.inject_reclaim(float(rng.integers(0, 50)), cells=spurious[k:k + 3])
+    whole = canonical(dims)
+    seen = set()
+    for stop in list(range(0, 60, 4)) + [None]:
+        svc.run(until=stop)
+        for prefs in [[cuboid() for _ in range(int(rng.integers(1, 4)))]
+                      for _ in range(3)] + [[whole]]:
+            assert FitTest(dims, prefs).packed == packed
+            expected = _linear_reservation(svc, prefs)
+            assert svc._reservation(prefs) == expected, (stop, prefs)
+            seen.add("none" if expected is None
+                     else "now" if expected == svc.now else "free")
+    assert seen == {"none", "now", "free"}
+
+
+@pytest.mark.parametrize("units", sorted(MIRA_SCHEDULER_PARTITIONS))
+def test_packed_fit_test_agrees_with_first_fit(units):
+    """On random grids of Mira's midplane torus, at every occupancy, the
+    packed test answers as ``first_fit`` does for every geometry of the
+    partition size, alone and all together."""
+    dims = (4, 4, 3, 2)
+    geometries = list(sub_cuboids(dims, units))
+    assert geometries
+    rng = np.random.default_rng(units)
+    grids = [np.zeros(dims, dtype=bool), np.ones(dims, dtype=bool)] + [
+        rng.random(dims) < density for density in np.linspace(0.02, 0.98, 60)
+    ]
+    answers = set()
+    for grid in grids:
+        for prefs in [[g] for g in geometries] + [geometries]:
+            fits = FitTest(dims, prefs)
+            assert fits.packed
+            expected = any(first_fit(grid, g) is not None for g in prefs)
+            assert fits(grid) == expected
+            answers.add(expected)
+    assert answers == {True, False}
