@@ -55,7 +55,16 @@ def _attn_flops(arch: ArchConfig, B: int, S: int, compiled: bool) -> float:
 
 def _qkvo_flops(arch: ArchConfig, tokens: float) -> float:
     d, H, K, hd = arch.d_model, arch.n_heads, arch.n_kv_heads, arch.resolved_head_dim
-    return 2 * tokens * (d * H * hd + 2 * d * K * hd + H * hd * d)
+    a = arch.resolved_attn_input_dim  # q/k/v read a; o writes d
+    return 2 * tokens * (a * H * hd + 2 * a * K * hd + H * hd * d)
+
+
+def _shared_block_flops(arch: ArchConfig, tokens: float, attn: float) -> float:
+    """One Zamba2 shared-block application (``attn``: its score and PV
+    flops): q/k/v/o, the gated MLP, its LoRA adapter and output linear."""
+    d, r = arch.d_model, arch.adapter_rank
+    adapter = 2 * tokens * (d * r + r * 2 * arch.d_ff + d * d)
+    return _qkvo_flops(arch, tokens) + attn + _mlp_flops(arch, tokens) + adapter
 
 
 def _mlp_flops(arch: ArchConfig, tokens: float) -> float:
@@ -117,13 +126,9 @@ def forward_flops(arch: ArchConfig, B: int, S: int, compiled: bool = True) -> Di
         out["layers"] = L * _rwkv_layer_flops(arch, B, S)
     elif arch.family == "hybrid":
         out["layers"] = L * _mamba_layer_flops(arch, B, S)
-        n_shared = L // arch.shared_attn_every
-        shared = (
-            _qkvo_flops(arch, tokens)
-            + _attn_flops(arch, B, S, compiled)
-            + _mlp_flops(arch, tokens)
+        out["shared_attn"] = len(arch.shared_applications) * _shared_block_flops(
+            arch, tokens, _attn_flops(arch, B, S, compiled)
         )
-        out["shared_attn"] = n_shared * shared
     else:
         per = _qkvo_flops(arch, tokens) + _attn_flops(arch, B, S, compiled)
         if arch.moe is not None:
@@ -147,11 +152,8 @@ def decode_flops(arch: ArchConfig, B: int, cache_len: int) -> Dict[str, float]:
         out["layers"] = L * _rwkv_layer_flops(arch, B, 1)
     elif arch.family == "hybrid":
         out["layers"] = L * _mamba_layer_flops(arch, B, 1)
-        n_shared = L // arch.shared_attn_every
         attn = 2 * 2 * B * 1 * cache_len * H * hd
-        out["shared_attn"] = n_shared * (
-            _qkvo_flops(arch, B) + attn + _mlp_flops(arch, B)
-        )
+        out["shared_attn"] = len(arch.shared_applications) * _shared_block_flops(arch, B, attn)
     else:
         kv = min(cache_len, arch.sliding_window) if arch.sliding_window else cache_len
         attn = 2 * 2 * B * 1 * kv * H * hd

@@ -1,4 +1,4 @@
-"""Assigned architecture configs (11 archs from the public pool) + shapes."""
+"""Registered architecture configs + shapes."""
 
 import importlib
 
@@ -23,6 +23,7 @@ _MODULES = [
     "internvl2_1b",
     "rwkv6_3b",
     "zamba2_2_7b",
+    "zamba2_7b",
     "mixtral_8x7b",
     "phi3_5_moe_42b",
     "llama3_70b",

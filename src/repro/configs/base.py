@@ -8,6 +8,7 @@ registered by id and selectable via ``--arch`` in the launchers.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field, replace
 from typing import Callable, Dict, Optional, Tuple
 
@@ -62,7 +63,17 @@ class ArchConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     rwkv: Optional[RWKVConfig] = None
-    shared_attn_every: int = 0  # Zamba2: shared attention block interval
+    # Zamba2 hybrids: at each layer of ``hybrid_layer_ids`` (those below
+    # n_layers) one of ``n_shared_blocks`` shared transformer blocks is
+    # applied, in turn, with an adapter of rank ``adapter_rank`` of its own
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    n_shared_blocks: int = 0
+    adapter_rank: int = 0
+    attn_input_dim: Optional[int] = None  # attention input width (default d_model)
+    norm_eps: Optional[float] = None  # default: 1e-6 rmsnorm, 1e-5 layernorm
+    gelu_exact: bool = False  # erf GELU (default: the tanh approximation)
+    # softmax scale (head_dim / attn_scale_divisor)^-1/2 (default 1/sqrt(head_dim))
+    attn_scale_divisor: float = 1.0
     # modality frontends (STUB: input_specs provides precomputed embeddings)
     frontend: str = "none"  # none | audio | vlm
     n_codebooks: int = 1  # MusicGen EnCodec codebooks
@@ -76,6 +87,27 @@ class ArchConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
+
+    @property
+    def resolved_attn_input_dim(self) -> int:
+        return self.attn_input_dim or self.d_model
+
+    @property
+    def attn_scale(self) -> float:
+        """Softmax scale: (head_dim / attn_scale_divisor)^-1/2."""
+        return 1.0 / math.sqrt(self.resolved_head_dim / self.attn_scale_divisor)
+
+    @property
+    def resolved_norm_eps(self) -> float:
+        if self.norm_eps is not None:
+            return self.norm_eps
+        return 1e-5 if self.norm == "layernorm" else 1e-6
+
+    @property
+    def shared_applications(self) -> Tuple[int, ...]:
+        """The hybrid layers among the ``n_layers`` served, in order; the
+        j-th applies shared block ``j % n_shared_blocks``."""
+        return tuple(i for i in self.hybrid_layer_ids if i < self.n_layers)
 
     @property
     def padded_vocab_size(self) -> int:
@@ -97,13 +129,15 @@ class ArchConfig:
         )
 
     def param_count(self) -> int:
-        """Approximate parameter count (embeddings included)."""
+        """Approximate parameter count (embeddings included; exact for hybrids)."""
+        if self.family == "hybrid":
+            return self._hybrid_param_count()
         d, ff, V = self.d_model, self.d_ff, self.vocab_size
         hd = self.resolved_head_dim
         qkv = d * hd * self.n_heads + 2 * d * hd * self.n_kv_heads + hd * self.n_heads * d
         if self.family == "ssm" and self.rwkv is not None:
             per_layer = 4 * d * d + 2 * d * ff  # r,k,v,o + channel mix
-        elif self.ssm is not None and self.family in ("ssm", "hybrid"):
+        elif self.ssm is not None and self.family == "ssm":
             d_in = self.ssm.expand * d
             # in_proj (x, z) + dt/B/C projections + out_proj
             per_layer = 2 * d * d_in + d * 2 * self.ssm.n_groups * self.ssm.state_dim + d_in * d
@@ -116,12 +150,26 @@ class ArchConfig:
             pass  # channel mix already counted
         elif self.ssm is None:
             per_layer += glu * d * ff
-        if self.shared_attn_every:
-            shared = qkv + 3 * d * ff
-        else:
-            shared = 0
         embed = V * d * (1 if self.tied_embeddings else 2) * self.n_codebooks
-        return self.n_layers * per_layer + shared + embed
+        return self.n_layers * per_layer + embed
+
+    def _hybrid_param_count(self) -> int:
+        """Every parameter of a Zamba2 hybrid: per-layer Mamba2 with its
+        norm, the shared blocks, one adapter and output linear per
+        application, the embedding (and head, untied), the final norm."""
+        s, d, ff = self.ssm, self.d_model, self.d_ff
+        d_in = s.expand * d
+        H, GN = d_in // s.head_dim, 2 * s.n_groups * s.state_dim
+        conv_ch = d_in + GN
+        mamba = (d * (2 * d_in + GN + H) + (s.conv_width + 1) * conv_ch  # conv weight, bias
+                 + 3 * H + d_in + d_in * d + d)
+        a, hd = self.resolved_attn_input_dim, self.resolved_head_dim
+        attn = a * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+        block = attn + 3 * d * ff + a + d
+        adapter = d * self.adapter_rank + self.adapter_rank * 2 * ff + d * d
+        embed = self.vocab_size * d * (1 if self.tied_embeddings else 2)
+        return (self.n_layers * mamba + self.n_shared_blocks * block
+                + len(self.shared_applications) * adapter + embed + d)
 
     def active_param_count(self) -> int:
         """Parameters active per token (MoE: top-k experts only)."""
@@ -135,7 +183,7 @@ class ArchConfig:
     def reduced(self) -> "ArchConfig":
         """Small same-family config for CPU smoke tests."""
         kw = dict(
-            n_layers=min(self.n_layers, 2 if not self.shared_attn_every else 4),
+            n_layers=min(self.n_layers, 2),
             d_model=64,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 2) if self.n_kv_heads < self.n_heads else 4,
@@ -151,8 +199,13 @@ class ArchConfig:
             kw["ssm"] = replace(self.ssm, state_dim=8, head_dim=16, chunk=8)
         if self.rwkv is not None:
             kw["rwkv"] = RWKVConfig(head_dim=16, decay_lora=8, gate_lora=8)
-        if self.shared_attn_every:
-            kw["shared_attn_every"] = 2
+        if self.hybrid_layer_ids:
+            # 7 layers, hybrids at unequal gaps: block 0 is applied twice,
+            # each time with its own adapter; attention fed [hidden, embedding]
+            kw.update(n_layers=7, hybrid_layer_ids=(1, 4, 6), adapter_rank=4)
+            if self.attn_input_dim:
+                kw.update(attn_input_dim=2 * kw["d_model"],
+                          head_dim=2 * kw["d_model"] // kw["n_heads"])
         return replace(self, name=self.name + "-smoke", **kw)
 
 

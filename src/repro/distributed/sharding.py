@@ -154,10 +154,9 @@ class ShardingRules:
     # -- parameters ---------------------------------------------------------------
     def param_spec(self, path: Tuple[str, ...], shape: Tuple[int, ...]) -> P:
         names = [str(getattr(p, "key", getattr(p, "idx", p))) for p in path]
-        # leading stacked-layer dims are never sharded
-        stack = 0
-        if "layers" in names or "mamba_layers" in names:
-            stack = 2 if "mamba_layers" in names else 1
+        # the leading stacked-layer dim is never sharded (zamba's layers
+        # are lists, one leaf per layer, with nothing stacked)
+        stack = 1 if "layers" in names else 0
         core = shape[stack:]
         leaf = names[-1] if names else ""
         spec = [None] * stack + list(self._core_spec(names, leaf, core))
@@ -266,26 +265,27 @@ class ShardingRules:
     # -- caches ---------------------------------------------------------------
     def cache_specs(self, cache_shapes: PyTree) -> PyTree:
         def spec(path, leaf):
-            names = [str(getattr(p, "key", getattr(p, "idx", p))) for p in path]
-            leafname = names[-1]
+            # zamba's cache entries are lists: the name is the list's key
+            leafname = next(str(p.key) for p in reversed(path) if hasattr(p, "key"))
             shape = leaf.shape
             if leafname in ("k", "v"):
-                # (L, B, S, K, hd) or zamba (G, B, S, K, hd)
-                L, B, S, K, hd = shape
+                # (L, B, S, K, hd), or zamba's per application (B, S, K, hd)
+                stack = len(shape) - 4
+                B, S, K, hd = shape[stack:]
                 dp = _shard_if(B, self.fsdp, self.mesh)
                 k_axis = self.tp(K)
                 s_axis = self.tp(S) if k_axis is None else None
-                return P(None, dp, s_axis, k_axis, None)
+                return P(*([None] * stack), dp, s_axis, k_axis, None)
             if leafname == "wkv":  # (L, B, H, P, P)
                 _, B, H, _, _ = shape
                 dp = _shard_if(B, self.fsdp, self.mesh)
                 return P(None, dp, self.tp(H), None, None)
-            if leafname == "ssm":  # (G, L, B, H, N, P)
-                dp = _shard_if(shape[2], self.fsdp, self.mesh)
-                return P(None, None, dp, self.tp(shape[3]), None, None)
-            if leafname == "conv":  # (G, L, B, K-1, C)
-                dp = _shard_if(shape[2], self.fsdp, self.mesh)
-                return P(None, None, dp, None, self.tp(shape[4]))
+            if leafname == "ssm":  # per layer (B, H, N, P)
+                dp = _shard_if(shape[0], self.fsdp, self.mesh)
+                return P(dp, self.tp(shape[1]), None, None)
+            if leafname == "conv":  # per layer (B, K-1, C)
+                dp = _shard_if(shape[0], self.fsdp, self.mesh)
+                return P(dp, None, self.tp(shape[2]))
             if leafname in ("shift_t", "shift_c"):  # (L, B, d)
                 dp = _shard_if(shape[1], self.fsdp, self.mesh)
                 return P(None, dp, None)

@@ -103,6 +103,7 @@ def flash_attention_hmajor(
     window: Optional[int] = None,
     blk_q: int = 128,
     blk_k: int = 128,
+    scale: Optional[float] = None,  # default 1/sqrt(hd)
     interpret: bool = False,
 ) -> jax.Array:
     B, H, S, hd = q.shape
@@ -113,7 +114,8 @@ def flash_attention_hmajor(
     blk_k = min(blk_k, S)
     assert S % blk_q == 0 and S % blk_k == 0
     n_q, n_k = S // blk_q, S // blk_k
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     kernel = functools.partial(
         _flash_kernel,

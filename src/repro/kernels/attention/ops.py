@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from .flash import flash_attention_hmajor
 
 
-@partial(jax.jit, static_argnames=("causal", "window", "blk_q", "blk_k", "interpret"))
+@partial(jax.jit, static_argnames=("causal", "window", "blk_q", "blk_k", "scale", "interpret"))
 def flash_attention(
     q: jax.Array,  # (B, S, H, hd)
     k: jax.Array,  # (B, S, K, hd)
@@ -26,6 +26,7 @@ def flash_attention(
     window: Optional[int] = None,
     blk_q: int = 128,
     blk_k: int = 128,
+    scale: Optional[float] = None,  # default 1/sqrt(hd)
     interpret: bool = False,
 ) -> jax.Array:
     qh = q.transpose(0, 2, 1, 3)
@@ -33,7 +34,7 @@ def flash_attention(
     vh = v.transpose(0, 2, 1, 3)
     out = flash_attention_hmajor(
         qh, kh, vh,
-        causal=causal, window=window, blk_q=blk_q, blk_k=blk_k,
+        causal=causal, window=window, blk_q=blk_q, blk_k=blk_k, scale=scale,
         interpret=interpret,
     )
     return out.transpose(0, 2, 1, 3)

@@ -177,10 +177,13 @@ def _lower_cell(arch, shape, mesh, *, microbatches, unroll, remat="block",
 
 
 def _calib_depths(arch):
-    if arch.shared_attn_every:
-        step = arch.shared_attn_every
-        return step, 2 * step, arch.n_layers // step, 1  # L0, L1, units_full, per
-    return 2, 4, arch.n_layers, None
+    """(L0, L1): two depths to extrapolate from.  A hybrid's are the depths
+    just past its first and its second shared-block application (the gaps
+    of the pattern vary, so the extrapolation is approximate there)."""
+    apps = arch.shared_applications
+    if len(apps) >= 2:
+        return apps[0] + 1, apps[1] + 1
+    return 2, 4
 
 
 def run_cell(arch_name: str, shape_name: str, mesh_kind: str, force: bool = False,
@@ -248,7 +251,7 @@ def run_cell(arch_name: str, shape_name: str, mesh_kind: str, force: bool = Fals
         # terms, constants).  Four unrolled compiles at (L0,1),(L1,1),(L0,2),
         # (L1,2) determine the coefficients exactly; prefill/decode cells use
         # the depth-only linear model (two compiles).
-        L0, L1, units_full, _ = _calib_depths(arch)
+        L0, L1 = _calib_depths(arch)
         mbs = (1, 2) if (shape.kind == "train" and mb > 1) else (1,)
         meas = {}
         ax_meas = {}

@@ -61,10 +61,10 @@ def apply_norm(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig) -> jax.Ar
     if cfg.norm == "layernorm":
         mean = xf.mean(-1, keepdims=True)
         var = ((xf - mean) ** 2).mean(-1, keepdims=True)
-        out = (xf - mean) * jax.lax.rsqrt(var + 1e-5) * p["scale"] + p["bias"]
+        out = (xf - mean) * jax.lax.rsqrt(var + cfg.resolved_norm_eps) * p["scale"] + p["bias"]
     else:  # rmsnorm
         ms = (xf * xf).mean(-1, keepdims=True)
-        out = xf * jax.lax.rsqrt(ms + 1e-6) * p["scale"]
+        out = xf * jax.lax.rsqrt(ms + cfg.resolved_norm_eps) * p["scale"]
     return out.astype(dtype)
 
 
@@ -107,12 +107,20 @@ def init_mlp(key, cfg: ArchConfig, d_ff: Optional[int] = None, dtype=None) -> Py
     }
 
 
-def apply_mlp(p: PyTree, x: jax.Array, cfg: ArchConfig) -> jax.Array:
+def apply_mlp(
+    p: PyTree, x: jax.Array, cfg: ArchConfig, lora: Optional[Tuple[jax.Array, jax.Array]] = None
+) -> jax.Array:
+    """``lora = (a, b)`` adds ``(x @ a) @ b`` to the gated MLP's projections:
+    its first ``d_ff`` columns to the gate (``wi``), the rest to ``wg``."""
     h = x @ p["wi"]
+    up = x @ p["wg"] if cfg.mlp_act.endswith("_glu") else None
+    if lora is not None:
+        lo_gate, lo_up = jnp.split((x @ lora[0]) @ lora[1], 2, axis=-1)
+        h, up = h + lo_gate, up + lo_up
     if cfg.mlp_act == "silu_glu":
-        h = jax.nn.silu(h) * (x @ p["wg"])
+        h = jax.nn.silu(h) * up
     elif cfg.mlp_act == "gelu_glu":
-        h = jax.nn.gelu(h) * (x @ p["wg"])
+        h = jax.nn.gelu(h, approximate=not cfg.gelu_exact) * up
     elif cfg.mlp_act == "relu2":
         h = jnp.square(jax.nn.relu(h))
     elif cfg.mlp_act == "gelu":
@@ -128,11 +136,12 @@ def apply_mlp(p: PyTree, x: jax.Array, cfg: ArchConfig) -> jax.Array:
 def init_attention(key, cfg: ArchConfig, dtype=None) -> PyTree:
     dtype = dtype or jnp.dtype(cfg.param_dtype)
     d, H, K, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    a = cfg.resolved_attn_input_dim  # input width; the output is d_model wide
     kq, kk, kv, ko = jax.random.split(key, 4)
     p = {
-        "wq": dense_init(kq, d, (d, H, hd), dtype),
-        "wk": dense_init(kk, d, (d, K, hd), dtype),
-        "wv": dense_init(kv, d, (d, K, hd), dtype),
+        "wq": dense_init(kq, a, (a, H, hd), dtype),
+        "wk": dense_init(kk, a, (a, K, hd), dtype),
+        "wv": dense_init(kv, a, (a, K, hd), dtype),
         "wo": dense_init(ko, H * hd, (H, hd, d), dtype),
     }
     if cfg.attn_bias:
@@ -190,7 +199,7 @@ def attention_xla(
     v = _expand_kv(v, H)
     kv_block = _largest_divisor_at_most(S, min(kv_block, S))
     n_blocks = S // kv_block
-    scale = 1.0 / math.sqrt(hd)
+    scale = cfg.attn_scale
     qf = q * scale
     kb = k.reshape(B, n_blocks, kv_block, H, hd).transpose(1, 0, 2, 3, 4)
     vb = v.reshape(B, n_blocks, kv_block, H, hd).transpose(1, 0, 2, 3, 4)
@@ -246,7 +255,7 @@ def attention_banded(
     q_block = _largest_divisor_at_most(S, min(q_block, S))
     n_blocks = S // q_block
     band = min(window + q_block, S)
-    scale = 1.0 / math.sqrt(hd)
+    scale = cfg.attn_scale
 
     def block_fn(i, q_i):
         # q_i: (B, q_block, H, hd)
@@ -282,7 +291,7 @@ def attention_decode(
     B, S, K, hd = k_cache.shape
     H = q.shape[2]
     reps = H // K
-    scale = 1.0 / math.sqrt(hd)
+    scale = cfg.attn_scale
     qg = (q * scale).reshape(B, 1, K, reps, hd)
     s = jnp.einsum("bqkrh,bskh->bqksr", qg, k_cache).astype(jnp.float32)
     pos = jnp.arange(S)
@@ -319,6 +328,7 @@ def run_attention(
             q, k, v,
             causal=True,
             window=cfg.sliding_window,
+            scale=cfg.attn_scale,
             interpret=(impl == "pallas_interpret"),
         )
     else:

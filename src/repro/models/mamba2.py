@@ -9,8 +9,11 @@ matmuls (C B^T ⊙ L) x; across chunks a small (H, N, P) state is carried by a
 scan.  This is also the blueprint of the Pallas kernel (repro/kernels/ssd).
 
 Structure per block (Mamba2 paper / Zamba2 usage):
-  in_proj -> [z | x | B | C | dt], causal depthwise conv on (x, B, C),
-  SSD, gated (silu(z)) output norm, out_proj.
+  in_proj -> [z | x | B | C | dt], causal depthwise conv with bias and
+  SiLU on (x, B, C), SSD with the D skip, gated
+  RMSNorm of y * silu(z) within each of the ``n_groups`` channel groups,
+  out_proj.  Heads are assigned to B/C groups contiguously: heads
+  [g*H/G, (g+1)*H/G) read group g.
 """
 
 from __future__ import annotations
@@ -45,6 +48,7 @@ def init_mamba2(key, cfg: ArchConfig, dtype) -> PyTree:
     return {
         "in_proj": dense_init(ks[0], d, (d, proj_out), dtype),
         "conv_w": (jax.random.normal(ks[1], (s.conv_width, d_in + 2 * G * N), jnp.float32) * 0.1),
+        "conv_b": jax.random.normal(ks[3], (d_in + 2 * G * N,), jnp.float32) * 0.1,
         "A_log": jnp.log(jnp.linspace(1.0, 16.0, H).astype(jnp.float32)),
         "D": jnp.ones((H,), jnp.float32),
         "dt_bias": jnp.log(jnp.expm1(jnp.full((H,), 1e-2, jnp.float32))),
@@ -53,11 +57,14 @@ def init_mamba2(key, cfg: ArchConfig, dtype) -> PyTree:
     }
 
 
-def causal_conv(x: jax.Array, w: jax.Array, state: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Depthwise causal conv1d.  x: (B,S,C), w: (K,C), state: (B,K-1,C)."""
+def causal_conv(
+    x: jax.Array, w: jax.Array, b: jax.Array, state: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """Depthwise causal conv1d with bias, then SiLU.  x: (B,S,C), w: (K,C),
+    b: (C,), state: (B,K-1,C)."""
     K = w.shape[0]
     xp = jnp.concatenate([state.astype(x.dtype), x], axis=1)
-    out = sum(xp[:, i : i + x.shape[1], :] * w[i] for i in range(K))
+    out = sum(xp[:, i : i + x.shape[1], :] * w[i] for i in range(K)) + b
     new_state = xp[:, -(K - 1) :, :] if K > 1 else state
     return jax.nn.silu(out), new_state
 
@@ -71,7 +78,8 @@ def ssd_chunked(
     state0: jax.Array,  # (B, H, N, P)
     chunk: int,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Chunked SSD scan.  Heads are assigned to B/C groups round-robin."""
+    """Chunked SSD scan.  Heads are assigned to B/C groups contiguously
+    (``jnp.repeat``): heads [g*H/G, (g+1)*H/G) read group g."""
     B, S, H, P = xh.shape
     G, N = Bm.shape[2], Bm.shape[3]
     Q = min(chunk, S)
@@ -123,6 +131,15 @@ def ssd_chunked(
     return y[:, :S_orig], state
 
 
+def gated_rms_norm(y: jax.Array, z: jax.Array, w: jax.Array, groups: int, eps: float) -> jax.Array:
+    """Mamba2's output norm: ``y * silu(z)`` RMS-normalised within each of
+    ``groups`` equal channel groups, times ``w``; float32."""
+    *lead, C = y.shape
+    g = (y * jax.nn.silu(z.astype(jnp.float32))).reshape(*lead, groups, C // groups)
+    g = g * jax.lax.rsqrt((g * g).mean(-1, keepdims=True) + eps)
+    return g.reshape(*lead, C) * w
+
+
 def apply_mamba2(
     p: PyTree,
     x: jax.Array,  # (B, S, d)
@@ -138,7 +155,7 @@ def apply_mamba2(
         proj, [d_in, 2 * d_in, 2 * d_in + G * N, 2 * d_in + 2 * G * N], axis=-1
     )
     conv_in = jnp.concatenate([xs, bm, cm], axis=-1)
-    conv_out, conv_state = causal_conv(conv_in, p["conv_w"], state["conv"])
+    conv_out, conv_state = causal_conv(conv_in, p["conv_w"], p["conv_b"], state["conv"])
     xs, bm, cm = jnp.split(conv_out, [d_in, d_in + G * N], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
     A = -jnp.exp(p["A_log"])  # (H,)
@@ -153,11 +170,8 @@ def apply_mamba2(
         s.chunk,
     )
     y = y + xh * p["D"][None, None, :, None]
-    y = y.reshape(B, S, d_in)
-    # gated RMSNorm (Mamba2)
-    y = y * jax.nn.silu(z.astype(jnp.float32))
-    ms = (y * y).mean(-1, keepdims=True)
-    y = (y * jax.lax.rsqrt(ms + 1e-6) * p["norm"]).astype(x.dtype)
+    y = gated_rms_norm(y.reshape(B, S, d_in), z, p["norm"], G, cfg.resolved_norm_eps)
+    y = y.astype(x.dtype)
     return y @ p["out_proj"], {"conv": conv_state, "ssm": ssm_state}
 
 

@@ -103,7 +103,7 @@ def test_decode_matches_prefill(name, rng):
 def test_cells_assignment():
     """long_500k applies only to sub-quadratic archs; all archs have >= 3 cells."""
     long_archs = {n for n in ARCH_NAMES if "long_500k" in cells(get_arch(n))}
-    assert long_archs == {"rwkv6-3b", "zamba2-2.7b", "mixtral-8x7b"}
+    assert long_archs == {"rwkv6-3b", "zamba2-2.7b", "zamba2-7b", "mixtral-8x7b"}
     for n in ARCH_NAMES:
         assert len(cells(get_arch(n))) >= 3
 
@@ -118,6 +118,7 @@ def test_param_counts_match_published_sizes():
         "phi3.5-moe-42b-a6.6b": (39e9, 44e9),
         "rwkv6-3b": (2.5e9, 5e9),
         "zamba2-2.7b": (2e9, 3.5e9),
+        "zamba2-7b": (7.0e9, 7.8e9),
         "musicgen-large": (1.5e9, 3.5e9),
         "internvl2-1b": (0.3e9, 1.2e9),
     }

@@ -3,6 +3,7 @@ every architecture x mesh size combination (the dry-run's core invariant)."""
 
 import jax
 import pytest
+from jax.sharding import PartitionSpec as P
 from _hypothesis_compat import given, settings, st
 
 from repro.configs import all_archs, get_arch
@@ -34,7 +35,7 @@ def _check_specs(arch_name, mesh_shape):
     params = jax.eval_shape(lambda: model.init(jax.random.key(0)))
     specs = rules.params_specs(params)
     flat_p = jax.tree_util.tree_flatten_with_path(params)[0]
-    flat_s = jax.tree.leaves(specs, is_leaf=lambda x: hasattr(x, "index"))
+    flat_s = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
     assert len(flat_p) == len(flat_s)
     for (path, leaf), spec in zip(flat_p, flat_s):
         assert len(spec) <= leaf.ndim, (path, spec, leaf.shape)
@@ -70,7 +71,7 @@ def test_cache_specs_valid(arch_name):
     cache = jax.eval_shape(lambda: model.init_cache(128, 1024))
     specs = rules.cache_specs(cache)
     flat_c = jax.tree_util.tree_flatten_with_path(cache)[0]
-    flat_s = jax.tree.leaves(specs, is_leaf=lambda x: hasattr(x, "index"))
+    flat_s = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
     for (path, leaf), spec in zip(flat_c, flat_s):
         for dim, axis in zip(leaf.shape, tuple(spec) + (None,) * leaf.ndim):
             if axis is None:
@@ -89,11 +90,11 @@ def test_pure_dp_layout_no_duplicate_axes():
     model = build_model(arch)
     params = jax.eval_shape(lambda: model.init(jax.random.key(0)))
     pspecs = rules.params_specs(params)
-    for spec in jax.tree.leaves(pspecs, is_leaf=lambda x: hasattr(x, "index")):
+    for spec in jax.tree.leaves(pspecs, is_leaf=lambda x: isinstance(x, P)):
         assert all(s is None for s in spec)  # ZeRO-1 + no TP: replicated params
     ospecs = rules.opt_specs(params)
     used = set()
-    for spec in jax.tree.leaves(ospecs, is_leaf=lambda x: hasattr(x, "index")):
+    for spec in jax.tree.leaves(ospecs, is_leaf=lambda x: isinstance(x, P)):
         flat = []
         for entry in spec:
             if entry is None:
