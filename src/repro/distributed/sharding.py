@@ -268,8 +268,16 @@ class ShardingRules:
             # zamba's cache entries are lists: the name is the list's key
             leafname = next(str(p.key) for p in reversed(path) if hasattr(p, "key"))
             shape = leaf.shape
+            if leafname in ("k", "v") and len(shape) == 3:
+                # zamba's per application (B, S, K*hd): the flat axis over
+                # the model axis only where that splits whole heads
+                B, S, _ = shape
+                dp = _shard_if(B, self.fsdp, self.mesh)
+                k_axis = self.tp(self.cfg.n_kv_heads)
+                s_axis = self.tp(S) if k_axis is None else None
+                return P(dp, s_axis, k_axis)
             if leafname in ("k", "v"):
-                # (L, B, S, K, hd), or zamba's per application (B, S, K, hd)
+                # the transformer's stacked ring buffer (L, B, S, K, hd)
                 stack = len(shape) - 4
                 B, S, K, hd = shape[stack:]
                 dp = _shard_if(B, self.fsdp, self.mesh)

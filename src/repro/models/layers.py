@@ -14,7 +14,8 @@ Attention paths:
                    static band of size (window + block); sub-quadratic.
 * ``pallas``     — the flash kernel in repro.kernels (TPU target; validated
                    on CPU via interpret mode).
-* decode         — single-token attention against a KV cache.
+* decode         — single-token attention against a KV cache: ``(B, S, K,
+                   hd)``, or flat ``(B, S, K*hd)`` (Zamba2's).
 """
 
 from __future__ import annotations
@@ -303,6 +304,42 @@ def attention_decode(
     s = jnp.where(valid[:, None, None, :, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=3)
     out = jnp.einsum("bqksr,bskh->bqkrh", p.astype(v_cache.dtype), v_cache)
+    return out.reshape(B, 1, H, hd).astype(q.dtype)
+
+
+def attention_decode_flat(
+    q: jax.Array,  # (B, 1, H, hd)
+    k_flat: jax.Array,  # (B, S, K*hd): head k's values in columns k*hd .. (k+1)*hd
+    v_flat: jax.Array,
+    length: jax.Array,  # (B,) or scalar: number of valid cache entries
+    cfg: ArchConfig,
+) -> jax.Array:
+    """``attention_decode`` over a cache whose heads share one flat axis.
+
+    The cache is never reshaped to 4-D, so the compiler keeps the flat axis
+    in lanes and the position a sublane row (a one-row write then touches
+    one row of tiles, not one lane of every tile).  Both contractions run
+    over the flat axis: the scores against q laid block-diagonally,
+    ``Q[b, k*hd + i, k*reps + r] = q[b, k*reps + r, i]``, and the output as
+    ``p . v`` for every head against every column, of which each head keeps
+    its own kv head's columns (a 0/1 mask).  Accumulation is float32."""
+    B, S, F = k_flat.shape
+    H, hd = q.shape[2], q.shape[3]
+    K = F // hd
+    reps = H // K
+    if cfg.sliding_window is not None:
+        raise ValueError("attention_decode_flat has no sliding window")
+    qg = (q[:, 0] * cfg.attn_scale).reshape(B, K, reps, hd)
+    q_blk = jnp.einsum("bkri,kl->blikr", qg, jnp.eye(K, dtype=q.dtype)).reshape(B, F, H)
+    s = jnp.einsum("bsj,bjh->bsh", k_flat, q_blk, preferred_element_type=jnp.float32)
+    valid = jnp.arange(S)[None, :] < jnp.broadcast_to(jnp.asarray(length), (B,))[:, None]
+    s = jnp.where(valid[:, :, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=1)
+    o = jnp.einsum("bsh,bsj->bhj", p.astype(v_flat.dtype), v_flat,
+                   preferred_element_type=jnp.float32)
+    own = jnp.arange(F)[None, :] // hd == jnp.arange(K)[:, None]  # (K, F)
+    o = jnp.where(own[None, :, None, :], o.reshape(B, K, reps, F), 0.0).sum(axis=1)
+    out = o.reshape(B, reps, K, hd).transpose(0, 2, 1, 3)
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 

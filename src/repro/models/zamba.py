@@ -15,8 +15,13 @@ gated MLP whose gate/up projection carries the application's own LoRA
 adapter, with no residual inside.
 
 The layers are unrolled: each layer's parameters and cache entries are
-leaves of their own, so a decode step updates every cache leaf in place
-(the cache is donated) and no slice of a stacked weight is taken.
+leaves of their own, so no slice of a stacked weight is taken.  The cache
+is donated and its leaves aliased, which alone does not make a token's
+write cheap: the TPU compiler lays a 4-D ``(B, S, K, hd)`` leaf out with
+the position in lanes, so writing one position rewrites the whole leaf.
+Each application's k and v are therefore flat, ``(B, S, K * hd)``, and
+read by ``attention_decode_flat``: the position is a sublane row, and a
+token's write touches one row of tiles.
 
 ``from_published`` loads weights held in the published checkpoint's module
 layout, and ``published_config`` gives a config in the published
@@ -34,13 +39,15 @@ from repro.configs.base import ArchConfig
 from .layers import (
     apply_mlp,
     apply_norm,
+    attention_decode_flat,
+    attention_output,
     dense_init,
     embed_init,
     init_attention,
     init_mlp,
     init_norm,
+    qkv_project,
     run_attention,
-    run_attention_decode,
 )
 from .mamba2 import apply_mamba2, init_mamba2, init_mamba2_state, ssm_dims
 from .transformer import logits_from_hidden
@@ -236,11 +243,12 @@ def forward(
 
 def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> PyTree:
     """Per layer the Mamba2's conv window and SSM state (float32); per
-    shared-block application one KV pair."""
+    shared-block application one KV pair, each ``(batch, max_len,
+    n_kv_heads * head_dim)`` with head k in columns ``k * head_dim ..``."""
     s = cfg.ssm
     d_in, H, P, N = ssm_dims(cfg)
     dtype = jnp.dtype(cfg.activation_dtype)
-    kv = (batch, max_len, cfg.n_kv_heads, cfg.resolved_head_dim)
+    kv = (batch, max_len, cfg.n_kv_heads * cfg.resolved_head_dim)
     n_apps = len(cfg.shared_applications)
     return {
         "conv": [
@@ -265,12 +273,21 @@ def decode_step(
     e = jnp.take(p["embed"], batch["tokens"], axis=0).astype(dtype)
     new = {name: list(leaves) for name, leaves in cache.items()}
 
+    def attend(j, ap, a):
+        """Write the token's k and v as one row of the flat leaves, then attend."""
+        q, k, v = qkv_project(ap, a, cfg, position[None])
+        B = a.shape[0]
+        kv = [jax.lax.dynamic_update_slice_in_dim(
+            cache[n][j], x.reshape(B, 1, -1).astype(cache[n][j].dtype), position, axis=1)
+            for n, x in (("k", k), ("v", v))]
+        ctx = attention_decode_flat(q, *kv, position + 1, cfg)
+        return attention_output(ap, ctx), kv
+
     def block(j, h):
-        kv_j = {"k": cache["k"][j], "v": cache["v"][j]}
-        attend = lambda ap, a: run_attention_decode(ap, a, cfg, kv_j, position, position)
         block_p = p["shared_blocks"][block_of(cfg, j)]
-        s, kv = shared_block(block_p, p["adapters"][j], h, e, cfg, attend)
-        new["k"][j], new["v"][j] = kv["k"], kv["v"]
+        s, kv = shared_block(block_p, p["adapters"][j], h, e, cfg,
+                             lambda ap, a: attend(j, ap, a))
+        new["k"][j], new["v"][j] = kv
         return s
 
     def mamba(i, x):

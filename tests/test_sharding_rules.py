@@ -79,6 +79,24 @@ def test_cache_specs_valid(arch_name):
             assert dim % axis_size(mesh, axis) == 0, (path, leaf.shape, spec)
 
 
+@pytest.mark.parametrize(
+    "mesh_shape,want",
+    [({"data": 4, "model": 8}, P("data", None, "model")),
+     ({"data": 2, "model": 64}, P("data", "model", None))],
+    ids=["whole-heads", "sequence"],
+)
+def test_zamba2_flat_kv_splits_whole_heads_or_the_sequence(mesh_shape, want):
+    """Zamba2's per-application ``(B, S, K*hd)`` leaves: the flat axis goes
+    over the model axis only where that splits the 32 kv heads whole (8
+    ways: 4 heads a shard), else the sequence does (64 ways)."""
+    arch = get_arch("zamba2-7b")
+    rules = ShardingRules(arch, FakeMesh(mesh_shape))
+    cache = jax.eval_shape(lambda: build_model(arch).init_cache(8, 1024))
+    specs = rules.cache_specs(cache)
+    assert cache["k"][0].shape == (8, 1024, arch.n_kv_heads * arch.resolved_head_dim)
+    assert specs["k"] == specs["v"] == [want] * len(arch.shared_applications)
+
+
 def test_pure_dp_layout_no_duplicate_axes():
     """opt4 layout: model axis disabled, batch/moments over both mesh axes —
     the ZeRO-1 opt_specs path must not emit duplicate axis entries."""

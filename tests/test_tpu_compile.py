@@ -102,20 +102,24 @@ def test_rwkv6_kernel_compiles_at_rwkv6_3b_widths(one_chip):
 # ---------------------------------------------------------------------------
 # The full-width serving step.
 # ---------------------------------------------------------------------------
-def _decode_step_bytes(one_chip, arch, B, max_len):
-    """Device bytes of the compiled decode step (arguments, outputs and
-    temporaries, less what the donated cache aliases)."""
+def _compile_decode_step(one_chip, arch, B, max_len):
+    """The decode step compiled with its cache donated."""
     from repro.models import build_model
 
     model = build_model(arch)
     params = _on(one_chip, model.init_shapes())
     cache = _on(one_chip, jax.eval_shape(lambda: model.init_cache(B, max_len)))
     batch = {"tokens": _spec(one_chip, (B, 1), jnp.int32)}
-    compiled = (
+    return (
         jax.jit(model.decode_step, donate_argnums=1)
         .lower(params, cache, batch, _spec(one_chip, (), jnp.int32))
         .compile()
-    )
+    ), cache
+
+
+def _step_bytes(compiled):
+    """Device bytes of a compiled decode step (arguments, outputs and
+    temporaries, less what the donated cache aliases)."""
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes > 0  # the cache is updated in place
     return (
@@ -126,23 +130,50 @@ def _decode_step_bytes(one_chip, arch, B, max_len):
     )
 
 
-def test_zamba2_decode_step_fits_one_chip(one_chip):
-    from repro.configs import get_arch
-
-    total = _decode_step_bytes(one_chip, get_arch("zamba2-2.7b"), 8, 2048)
-    assert total < V5E_HBM_BYTES, f"{total / 1e9:.2f} GB"
-
-
-def test_zamba2_7b_stage_fits_one_chip(one_chip):
+@pytest.fixture(scope="module")
+def zamba2_7b_stage(one_chip):
     """``zamba2-7b`` cut to its first 24 layers (the ``zamba2-7b.chat``
-    cell's program) at 64 requests and a 256-position cache."""
+    cell's program) at 64 requests and a 256-position cache, compiled once
+    for the tests below."""
     import dataclasses
 
     from repro.configs import get_arch
 
     arch = dataclasses.replace(get_arch("zamba2-7b"), n_layers=24)
-    total = _decode_step_bytes(one_chip, arch, 64, 256)
+    return _compile_decode_step(one_chip, arch, 64, 256)
+
+
+def test_zamba2_decode_step_fits_one_chip(one_chip):
+    from repro.configs import get_arch
+
+    compiled, _ = _compile_decode_step(one_chip, get_arch("zamba2-2.7b"), 8, 2048)
+    total = _step_bytes(compiled)
+    assert total < V5E_HBM_BYTES, f"{total / 1e9:.2f} GB"
+
+
+def test_zamba2_7b_stage_fits_one_chip(zamba2_7b_stage):
+    total = _step_bytes(zamba2_7b_stage[0])
     assert 10e9 < total < V5E_HBM_BYTES, f"{total / 1e9:.2f} GB"
+
+
+def test_zamba2_7b_kv_write_touches_rows_not_lanes(zamba2_7b_stage):
+    """Each application's k and v leaf, ``bf16[64,256,7168]``, keeps the
+    position (axis 1) off the minor dimension, in the parameters and in
+    the ``dynamic-update-slice`` that writes a token, so that write touches
+    one row of tiles and not one lane of every tile; no cache-sized copy
+    is made to feed the attention, and the whole cache stays aliased."""
+    import re
+
+    compiled, cache = zamba2_7b_stage
+    text = compiled.as_text()
+    kv = r"bf16\[64,256,7168\]\{(\d+)[,\d]*(?::[^}]*)?\}"
+    minors = re.findall(kv, text)
+    writes = re.findall(kv + r" dynamic-update-slice\(", text)
+    assert len(writes) == 8  # k and v of four applications
+    assert minors and "1" not in minors, sorted(set(minors))
+    assert not re.search(kv + r" copy(?:-start)?\(", text)
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
 
 
 # ---------------------------------------------------------------------------

@@ -197,3 +197,27 @@ def test_param_count_is_the_models(name):
     params = jax.eval_shape(lambda: build_model(cfg).init(jax.random.key(0)))
     pad = (cfg.padded_vocab_size - cfg.vocab_size) * cfg.d_model
     assert sum(x.size for x in jax.tree.leaves(params)) == cfg.param_count() + pad
+
+
+@pytest.mark.parametrize("reps", [1, 2])
+@pytest.mark.parametrize("length", [1, 8, 16])
+def test_flat_decode_attention_matches_4d(length, reps):
+    """``attention_decode_flat`` over the flat ``(B, S, K*hd)`` cache against
+    ``attention_decode`` over the same cache as ``(B, S, K, hd)`` in float32,
+    at a head width off the 128 lanes (hd 24), with one, a middle and all of
+    16 positions valid and the invalid ones holding noise, and with 2 query
+    heads per kv head."""
+    from repro.models.layers import attention_decode, attention_decode_flat
+
+    Bq, S, K, hd = 3, 16, 4, 24
+    cfg = get_arch("zamba2-7b").reduced()
+    kq, kk, kv = jax.random.split(jax.random.key(length * reps), 3)
+    q = jax.random.normal(kq, (Bq, 1, K * reps, hd)).astype(jnp.bfloat16)
+    k = jax.random.normal(kk, (Bq, S, K, hd)).astype(jnp.bfloat16)
+    v = jax.random.normal(kv, (Bq, S, K, hd)).astype(jnp.bfloat16)
+    got = attention_decode_flat(q, k.reshape(Bq, S, K * hd), v.reshape(Bq, S, K * hd),
+                                jnp.array(length), cfg)
+    f32 = lambda x: x.astype(jnp.float32)
+    want = attention_decode(f32(q), f32(k), f32(v), jnp.array(length), cfg)
+    assert got.shape == want.shape and got.dtype == q.dtype
+    assert rel_l2(got.reshape(Bq, -1), want.reshape(Bq, -1)) < 1e-2
