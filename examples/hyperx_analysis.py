@@ -30,11 +30,11 @@ from repro.network import (
     MachineState,
     advise_partition,
     bisection_table,
-    compare_fabric_routing,
+    compare_routing,
     hyperx_all_to_all_max_load,
     optimal_cuboid,
-    simulate_fabric_traffic,
     simulate_queue,
+    simulate_traffic,
 )
 from repro.network.patterns import all_to_all, bisection_pairing, hotspot_line
 from repro.obs.contention import attribute_contention, render_dashboard
@@ -92,7 +92,7 @@ print("\n== Flow-simulated all-to-all per geometry (netsim over fabric.links) ==
 makespans = {}
 for g, _ in ranked:
     sub = POD.sub_fabric(g)
-    sim = simulate_fabric_traffic(sub, all_to_all(sub.dims))
+    sim = simulate_traffic(sub, all_to_all(sub.dims))
     pred = hyperx_all_to_all_max_load(sub)
     makespans[g] = sim.makespan
     assert sim.makespan == pred, (g, sim.makespan, pred)
@@ -103,8 +103,8 @@ print(f"  -> worst/best simulated gap x{gap:.1f} (>= 1.5: geometry dominates)")
 
 
 print("\n== What DAL routing recovers (minimal vs dimension-adaptive) ==")
-pairing_cmp = compare_fabric_routing(POD, bisection_pairing(POD.dims))
-hotspot_cmp = compare_fabric_routing(POD, hotspot_line(POD.dims))
+pairing_cmp = compare_routing(POD, bisection_pairing(POD.dims))
+hotspot_cmp = compare_routing(POD, hotspot_line(POD.dims))
 print(
     f"  pairing on H{POD.dims}: makespan {pairing_cmp.dor_makespan:.2f} -> "
     f"{pairing_cmp.adaptive_makespan:.2f}, recovered "

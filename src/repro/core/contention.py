@@ -29,8 +29,8 @@ from repro.network.routing import (  # noqa: F401,E402
     predict_pairing_time,
     route_dor,
     simulate_pattern,
-    uniform_offset_max_load,
 )
+from repro.network.geometry import uniform_offset_max_load  # noqa: F401,E402
 from repro.network.patterns import (  # noqa: F401
     bisection_pairing,
     furthest_offset,

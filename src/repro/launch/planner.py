@@ -69,16 +69,15 @@ from repro.network.collectives import (
     assign_axes,
 )
 from repro.network.fabric import (
-    HyperXFabric,
+    Fabric,
     TorusFabric,
     ranked_slice_geometries,
     slice_fabric,
 )
-from repro.network.geometry import Geometry, canonical, volume
-from repro.network.isoperimetry import ranked_geometries, scaled_node_dims
+from repro.network.geometry import Geometry, canonical, scaled_node_dims, volume
+from repro.network.isoperimetry import ranked_geometries
 from repro.network.mapping import RankMapping, map_ranks
-from repro.network.netsim import simulate_fabric_traffic, simulate_traffic
-from repro.network.routing import predict_pairing_time
+from repro.network.netsim import simulate_traffic
 from repro.obs.trace import TRACER as _TRACER
 
 __all__ = [
@@ -376,7 +375,7 @@ class PlanCandidate:
     geometry_rank: int  # index in the bisection-ranked geometry list
     bisection_links: int
     bisection_efficiency: float  # this geometry's bisection / best rankable
-    fabric: Union[TorusFabric, HyperXFabric]
+    fabric: Fabric
     rule: ShardingRuleSet
     mapping: Optional[RankMapping]
     assignment: AxisAssignment
@@ -439,7 +438,7 @@ def _decode_cache_bytes(cfg: ArchConfig, shape: ShapeConfig) -> float:
 def price_candidate(
     cfg: ArchConfig,
     shape: ShapeConfig,
-    fabric: Union[TorusFabric, HyperXFabric],
+    fabric: Fabric,
     node_dims: Geometry,
     n_compute: int,
     rule: ShardingRuleSet,
@@ -474,35 +473,17 @@ def price_candidate(
         return priced
 
 
-def _ring_equivalent(fabric: HyperXFabric) -> TorusFabric:
-    """Wrapped-torus stand-in for pricing ring schedules on a HyperX box.
-
-    A ring pass along one dim of a clique uses one direct link per hop
-    stage, exactly like a fully-wrapped torus dim — so ring-collective
-    times on ``H(S)`` equal those on the wrapped torus of the same dims
-    with per-link bandwidth ``K_k * link_bw`` (exact when the trunking is
-    uniform; the min-multiplicity floor makes the price conservative
-    otherwise).  ``double_link_on_2=False`` because a size-2 clique dim
-    has its ``K_k`` trunked links already counted in the bandwidth scale,
-    not the torus's two parallel wrap links.
-    """
-    bw = fabric.link_bw * min(fabric.link_multiplicity)
-    return TorusFabric(
-        fabric.dims, (True,) * len(fabric.dims), bw, double_link_on_2=False
-    )
-
-
 def _price_candidate_impl(
     cfg: ArchConfig,
     shape: ShapeConfig,
-    fabric: Union[TorusFabric, HyperXFabric],
+    fabric: Fabric,
     node_dims: Geometry,
     n_compute: int,
     rule: ShardingRuleSet,
     backend: Optional[str] = None,
 ) -> Optional[Tuple[Optional[RankMapping], AxisAssignment, Tuple, float, float, float, float, float]]:
     chips = fabric.num_chips
-    ring_fab = _ring_equivalent(fabric) if isinstance(fabric, HyperXFabric) else fabric
+    ring_fab = fabric.ring_equivalent()
     entries = rule_traffic(cfg, shape, rule.axis_sizes)
     pair_chip = pairing_stress_volume(entries, rule.axis_sizes)
     traffic = rule_rank_traffic(rule.axis_sizes, entries, pair_chip)
@@ -532,23 +513,7 @@ def _price_candidate_impl(
     # Node-level pairing stress: per-chip volume rescaled to the node torus
     # (identity on chip-level fabrics where volume(node_dims) == chips).
     pair_node = pair_chip * chips / volume(node_dims)
-    pairing_time = 0.0
-    if pair_node > 0.0 and isinstance(fabric, HyperXFabric):
-        # Halving-doubling partners differ in one coordinate of the split
-        # dim, so every pair has its own direct clique link: max link load
-        # is 1 and the exchange drains in one contention-free stage over a
-        # K_k-trunked link (netsim measures exactly this; see
-        # tests/test_hyperx.py).
-        sides = fabric.dims
-        if max(sides) > 1:
-            k = max(range(len(sides)), key=lambda i: sides[i])
-            pairing_time = pair_node / (fabric.link_bw * fabric.link_multiplicity[k])
-    elif pair_node > 0.0:
-        pred = predict_pairing_time(
-            node_dims, 1.0, fabric.link_bw,
-            double_link_on_2=fabric.double_link_on_2,
-        )
-        pairing_time = pair_node * pred.time_per_volume
+    pairing_time = fabric.pairing_time(pair_node, node_dims) if pair_node > 0.0 else 0.0
     cost = cell_cost(
         cfg, shape, float(cfg.param_count()),
         cache_bytes=_decode_cache_bytes(cfg, shape),
@@ -630,7 +595,7 @@ def plan_model(
     arch: Union[str, ArchConfig],
     chips: Optional[int] = None,
     *,
-    pod: Optional[Union[TorusFabric, HyperXFabric]] = None,
+    pod: Optional[Fabric] = None,
     shape: Union[str, ShapeConfig] = "decode_32k",
     wrap_mode: str = "slice",
     unit_node_dims: Optional[Sequence[int]] = None,
@@ -664,31 +629,18 @@ def plan_model(
     shape_cfg = shape if isinstance(shape, ShapeConfig) else SHAPES[shape]
     pod = pod or _default_pod()
     budget = chips if chips is not None else min(default_chip_budget(cfg), pod.num_chips)
-    if isinstance(pod, HyperXFabric):
-        if wrap_mode not in ("slice", "torus"):
-            raise ValueError(f"wrap_mode must be 'slice' or 'torus', got {wrap_mode!r}")
-        if unit_node_dims is not None:
-            raise ValueError(
-                "unit_node_dims is the BG/Q torus node-scaling convention; "
-                "HyperX pods plan over allocation-unit boxes directly"
-            )
-        ranked = ranked_geometries(pod, budget)
-        fabrics = [(g, bis, pod.sub_fabric(g)) for g, bis in ranked]
-        nodes = [fab.dims for _, _, fab in fabrics]
-    elif wrap_mode == "slice":
+    if wrap_mode not in ("slice", "torus"):
+        raise ValueError(f"wrap_mode must be 'slice' or 'torus', got {wrap_mode!r}")
+    if wrap_mode == "slice" and pod.boxes_share_links:
         ranked = ranked_slice_geometries(pod, budget)
         fabrics = [(g, bis, slice_fabric(pod, g)) for g, bis in ranked]
         nodes = [fab.dims for _, _, fab in fabrics]
-    elif wrap_mode == "torus":
-        ranked = ranked_geometries(pod.dims, budget, unit_node_dims)
-        fabrics = [
-            (g, bis, TorusFabric(g, (True,) * len(g), pod.link_bw,
-                                 double_link_on_2=pod.double_link_on_2))
-            for g, bis in ranked
-        ]
-        nodes = [scaled_node_dims(g, unit_node_dims) for g, _ in ranked]
     else:
-        raise ValueError(f"wrap_mode must be 'slice' or 'torus', got {wrap_mode!r}")
+        # Where boxes share no links (HyperX) a box is its own sub-fabric
+        # wherever it sits, so slice and torus semantics coincide.
+        ranked = ranked_geometries(pod, budget, unit_node_dims)
+        fabrics = [(g, bis, pod.sub_fabric(g)) for g, bis in ranked]
+        nodes = [scaled_node_dims(g, unit_node_dims) for g, _ in ranked]
     best_bis = ranked[0][1]
     rules = enumerate_rules(cfg, budget)
     rows: List[PlanCandidate] = []
@@ -748,13 +700,8 @@ def _simulate(cand: PlanCandidate) -> float:
     src, dst, vol = cand.mapping.machine_traffic()
     if len(vol) == 0 or float(np.sum(vol)) <= 0.0:
         return 1.0
-    if isinstance(cand.fabric, HyperXFabric):
-        sim = simulate_fabric_traffic(
-            cand.fabric, (src, dst, vol), link_bw=cand.fabric.link_bw
-        )
-        return max(1.0, float(sim.slowdown))
     sim = simulate_traffic(
-        cand.fabric.dims, (src, dst, vol),
+        cand.fabric, (src, dst, vol),
         link_bw=cand.fabric.link_bw,
         double_link_on_2=cand.fabric.double_link_on_2,
     )

@@ -9,24 +9,29 @@ Single home of every geometry / fabric / routing primitive in the repo
                 same-volume geometry via divisor meshgrids, Theorem 2.1/3.1
                 bounds with tightness certificates, bisection tables, and
                 the partition advisor (current-policy vs optimal geometry
-                with predicted + simulated speedups); fabric-dispatching
-                (Hamming cut closed forms + Lindsey bisections on HyperX).
+                with predicted + simulated speedups); the cut forms and
+                bounds come from the fabric (torus or HyperX).
   fabric      — the abstract Fabric interface (explicit ``links()``
-                incidence tables) with TorusFabric (per-dimension wrap
+                incidence tables, plus every fact that differs between
+                fabric kinds: box closure, the geometry-sensitive
+                exchange, cut forms and subset bounds, routing modes, the
+                ring stand-in) with TorusFabric (per-dimension wrap
                 flags, BG/Q double-link vs TPU single-link conventions)
                 and HyperXFabric (Hamming graph: per-dim cliques with
-                trunked link multiplicities) implementations, Torus compat
-                wrapper, slice planning.
+                trunked link multiplicities) implementations, ``as_fabric``
+                (plain dims -> the BG/Q torus), Torus compat wrapper,
+                slice planning.
   hamming     — Hamming-graph edge-isoperimetry closed forms: aligned-box
                 cuts, Lindsey lex bound, packed-edges fallback, exact
                 bisections.
   routing     — vectorized NumPy DOR link-load engine, closed-form
                 translation-invariant fast paths, pairing predictions;
-                HyperX minimal + DAL routing behind ``route_pattern``.
+                the HyperX minimal + DAL engine ``route_hyperx``.
   patterns    — traffic-pattern library (bisection pairing, all-to-all,
                 halo exchange, ring collectives, permutations, transpose).
   netsim      — vectorized flow-level simulator: max-min fair link
-                sharing over DOR or minimal-adaptive paths, phased
+                sharing over any fabric's paths (torus DOR or
+                minimal-adaptive, HyperX minimal or DAL), phased
                 collective schedules, prediction validation.
   collectives — jax.lax collective cost model + mesh-axis assignment.
   placement   — vectorized cuboid-placement engine: all free translates via
@@ -79,7 +84,10 @@ from .geometry import (
     degree_contribution,
     enumerate_vertices,
     factorizations,
+    pairing_max_load,
+    scaled_node_dims,
     sub_cuboids,
+    uniform_offset_max_load,
     volume,
 )
 from .geometry import bisection_links as torus_bisection_links
@@ -100,7 +108,6 @@ from .isoperimetry import (
     lemma32_cut,
     optimal_cuboid,
     ranked_geometries,
-    scaled_node_dims,
     small_set_expansion,
     theorem31_bound,
     worst_bisection_geometry,
@@ -114,6 +121,7 @@ from .fabric import (
     LinkTable,
     Torus,
     TorusFabric,
+    as_fabric,
     best_slice_geometry,
     ranked_slice_geometries,
     slice_fabric,
@@ -124,22 +132,20 @@ from .hamming import (
     hamming_cut_aligned,
     hamming_cut_of_set,
     hamming_subset_bound,
+    hyperx_all_to_all_max_load,
     lindsey_bound,
 )
 from .routing import (
     LinkLoads,
     PairingPrediction,
     all_to_all_max_load,
-    hyperx_all_to_all_max_load,
     hyperx_max_link_load,
     max_link_load,
     pairing_speedup,
     predict_pairing_time,
     route_dor,
     route_hyperx,
-    route_pattern,
     simulate_pattern,
-    uniform_offset_max_load,
 )
 from .patterns import (
     all_to_all,
@@ -165,12 +171,9 @@ from .netsim import (
     UtilizationSample,
     adaptive_paths,
     build_paths,
-    compare_fabric_routing,
     compare_routing,
     dor_paths,
-    fabric_paths,
     link_capacities,
-    simulate_fabric_traffic,
     simulate_flows,
     simulate_phases,
     simulate_traffic,

@@ -49,9 +49,9 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..obs.trace import TRACER as _TRACER
-from .fabric import HyperXFabric, TorusFabric
-from .geometry import Geometry, bisection_links, canonical, sub_cuboids
-from .isoperimetry import ranked_geometries, scaled_node_dims
+from .fabric import as_fabric
+from .geometry import Geometry, canonical, scaled_node_dims, sub_cuboids
+from .isoperimetry import ranked_geometries
 from .mapping import RankMapping, map_ranks
 from .netsim import dor_paths, simulate_flows
 from .placement import (
@@ -134,16 +134,12 @@ class MachineState:
     """
 
     def __init__(self, dims: Sequence[int], backend: Optional[str] = None):
-        # Accepts plain allocation-unit dims (torus semantics, historical
-        # default) or a Fabric.  HyperX occupancy uses the same grid: a
-        # clique dimension is invariant under coordinate relabeling, so a
-        # wrapped translate of a box is just another valid aligned box.
-        if isinstance(dims, (TorusFabric, HyperXFabric)):
-            self.fabric: Optional[object] = dims
-            self.dims = dims.dims
-        else:
-            self.fabric = None
-            self.dims = tuple(int(d) for d in dims)
+        # Accepts plain allocation-unit dims (the paper's torus) or a
+        # Fabric.  HyperX occupancy uses the same grid: a clique dimension
+        # is invariant under coordinate relabeling, so a wrapped translate
+        # of a box is just another valid aligned box.
+        self.fabric = as_fabric(dims)
+        self.dims = self.fabric.dims
         self.grid = np.zeros(self.dims, dtype=bool)
         self.placements: Dict[int, Placement] = {}
         # Exact accumulator: per placement size n, the int64 sum of the
@@ -158,19 +154,6 @@ class MachineState:
     @property
     def free_units(self) -> int:
         return int((~self.grid).sum())
-
-    @property
-    def fabric_or_dims(self):
-        """The fabric this machine was built from, or its plain dims — the
-        value fabric-dispatching engines (isoperimetry, routing) accept."""
-        return self.fabric if self.fabric is not None else self.dims
-
-    def _geometry_bisection(self, geometry: Geometry) -> int:
-        """Internal bisection of a canonical geometry under this machine's
-        fabric convention (Hamming sub-box on HyperX, wrapped torus else)."""
-        if isinstance(self.fabric, HyperXFabric):
-            return self.fabric.sub_fabric(geometry).bisection_links()
-        return bisection_links(geometry)
 
     def cells(self, oriented: Sequence[int], offset: Coord) -> Tuple[np.ndarray, ...]:
         return placement_cells(self.dims, oriented, offset)
@@ -214,7 +197,7 @@ class MachineState:
         -contention background of that job (callers previously subtracted
         the float field after the fact and relied on the residue staying
         under the sharing threshold)."""
-        if isinstance(self.fabric, HyperXFabric):
+        if not self.fabric.boxes_share_links:
             raise TypeError(
                 "traffic_loads is the torus-routed background field; on a "
                 "HyperX fabric disjoint aligned boxes share no links (every "
@@ -248,7 +231,7 @@ class MachineState:
             oriented=oriented,
             offset=offset,
             bisection_links=(
-                self._geometry_bisection(canonical(geometry))
+                int(self.fabric.box_bisections([canonical(geometry)])[0])
                 if bisection is None
                 else bisection
             ),
@@ -277,11 +260,11 @@ class MachineState:
     def allocate_scored(self, job_id: int, geometry: Sequence[int]) -> Optional[Placement]:
         """Contention/contact-scored allocation of one geometry.
 
-        On a HyperX machine placement scoring is vacuous — minimal (and
-        DAL) paths of an aligned box never leave the box's own links, so
+        Where disjoint boxes share no links (HyperX: minimal and DAL paths
+        of an aligned box never leave it) placement scoring is vacuous —
         every free translate predicts exactly zero shared-link contention
         — and this degrades to first-fit with a 0.0 score."""
-        if isinstance(self.fabric, HyperXFabric):
+        if not self.fabric.boxes_share_links:
             return self.allocate(job_id, geometry)
         cand: Optional[ScoredPlacement] = best_placement(
             self.grid, geometry, self.traffic_loads(), backend=self.backend
@@ -418,7 +401,7 @@ class IsoperimetricPolicy(AllocationPolicy):
 
     def geometry_preferences(self, machine: MachineState, units: int) -> List[Geometry]:
         try:
-            return [g for g, _ in ranked_geometries(machine.fabric_or_dims, units)]
+            return [g for g, _ in ranked_geometries(machine.fabric, units)]
         except ValueError:
             return []  # no cuboid of this size fits (matches the old empty sort)
 
@@ -489,7 +472,7 @@ class ContentionScoredPolicy(AllocationPolicy):
 
     def geometry_preferences(self, machine: MachineState, units: int) -> List[Geometry]:
         try:
-            ranked = ranked_geometries(machine.fabric_or_dims, units)
+            ranked = ranked_geometries(machine.fabric, units)
         except ValueError:
             return []
         if self.min_bisection_efficiency > 0.0 and ranked[0][1] > 0:
@@ -709,13 +692,9 @@ def simulate_queue(
     # measurements ride on the service's start/release hooks.
     from .scheduler import SchedulerService
 
-    fabric = (
-        machine_dims
-        if isinstance(machine_dims, (TorusFabric, HyperXFabric))
-        else None
-    )
-    dims = fabric.dims if fabric is not None else tuple(int(d) for d in machine_dims)
-    if isinstance(fabric, HyperXFabric) and (measure or mapping_pattern is not None):
+    fabric = as_fabric(machine_dims)
+    dims = fabric.dims
+    if not fabric.boxes_share_links and (measure or mapping_pattern is not None):
         raise ValueError(
             "contention measurement replays torus routing; on a HyperX "
             "machine disjoint boxes share no links, so there is nothing to "
@@ -804,7 +783,7 @@ def simulate_queue(
         live_traffic.pop(job_id, None)
 
     service = SchedulerService(
-        fabric if fabric is not None else dims,
+        fabric,
         policy,
         unit_node_dims=unit_node_dims,
         link_bw=link_bw,

@@ -744,31 +744,32 @@ def _cut_fn():
     if _CUT is None:
         jax, jnp = _jax()
 
-        def fn(S, av):
-            # 2t / S_k is twice the product of the other sides: no integer
+        def fn(S, L):
+            # t / S_k is the product of the other sides: no integer
             # division, which 64-bit TPU arithmetic can only emulate
             D = S.shape[1]
             cut = jnp.zeros(S.shape[0], S.dtype)
             for k in range(D):
                 others = jnp.prod(S[:, [j for j in range(D) if j != k]], axis=1)
-                cut = cut + jnp.where(S[:, k] == av[k], 0, 2 * others)
+                cut = cut + others * L[:, k]
             return cut
 
         _CUT = jax.jit(fn)
     return _CUT
 
 
-def xla_cut_scores(dims: Sequence[int], assignments: np.ndarray, t: int) -> np.ndarray:
+def xla_cut_scores(assignments: np.ndarray, line_cuts: np.ndarray, t: int) -> np.ndarray:
     """XLA port of the isoperimetry engine's closed-form cut evaluation:
     for each aligned side assignment ``S`` of a volume-``t`` cuboid, the
-    exact cut ``sum_k (0 if S_k == dims_k else 2t / S_k)`` — int64
-    arithmetic under x64, so the scores equal the NumPy engine's
+    exact cut ``sum_k (t / S_k) * line_cuts_k`` given the per-side line
+    cuts of the fabric (:meth:`repro.network.fabric.Fabric.line_cuts`) —
+    int64 arithmetic under x64, so the scores equal the NumPy engine's
     **exactly**.  Every row's sides must multiply to ``t``."""
-    av = np.asarray(tuple(int(a) for a in dims), dtype=np.int64)
     S = np.asarray(assignments, dtype=np.int64)
+    L = np.asarray(line_cuts, dtype=np.int64)
     if S.shape[0] == 0:
         return np.zeros(0, dtype=np.int64)
     if not (S.prod(axis=1) == int(t)).all():
         raise ValueError(f"every assignment's sides must multiply to t={t}")
     with _x64():
-        return np.asarray(_cut_fn()(S, av), dtype=np.int64)
+        return np.asarray(_cut_fn()(S, L), dtype=np.int64)

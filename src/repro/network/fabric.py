@@ -20,6 +20,15 @@ Hardware conventions (see DESIGN.md):
 * TPU ICI: a slice gets wrap-around links in a dimension only when it spans
   that full pod dimension, and a length-2 dimension has a single link —
   ``TorusFabric.tpu(dims, wrap)`` / :func:`slice_fabric`.
+
+Every engine above this module (routing, netsim, placement, allocation,
+the scheduler, the planner, the contention report) takes a
+:class:`Fabric` (or plain dims, through :func:`as_fabric`) and asks it,
+never its type, what differs between fabric kinds: whether disjoint
+boxes share links, which exchange separates box geometries, the cut
+closed forms and the subset bound, the routing modes, and the ring
+stand-in for collective pricing.  This module imports only
+:mod:`~repro.network.geometry` and :mod:`~repro.network.hamming`.
 """
 
 from __future__ import annotations
@@ -32,7 +41,14 @@ from typing import Iterable, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from . import geometry, hamming
-from .geometry import Geometry, canonical, volume
+from .geometry import (
+    Geometry,
+    canonical,
+    pairing_max_load,
+    scaled_node_dims,
+    theorem31_bound,
+    volume,
+)
 
 # TPU v5e-class constants (per chip / per link, bytes per second).
 DEFAULT_LINK_BW = 50e9  # ~50 GB/s per ICI link per direction (prompt spec)
@@ -65,7 +81,8 @@ class LinkTable:
 
     def dense_capacities(self) -> np.ndarray:
         """Per-slot capacities (bytes/s), zero on unused slots — the
-        vector :func:`repro.network.netsim.fabric_paths` waterfills over."""
+        vector :func:`repro.network.netsim.build_paths` hands the drain
+        for fabrics without the torus tensor layout."""
         cap = np.zeros(self.n_slots, dtype=np.float64)
         cap[self.link] = self.capacity
         return cap
@@ -85,10 +102,31 @@ class Fabric(abc.ABC):
     from it), neighbor queries, and an internal-bisection figure.
     Implementations: :class:`TorusFabric` (rings per dimension) and
     :class:`HyperXFabric` (a clique per dimension — the Hamming graph).
+
+    The members below carry every fact the engines used to branch on by
+    type.  Boxes are aligned sub-boxes (cuboid placements), given by
+    their sides ``geometry``; ``unit_node_dims`` is the Blue Gene/Q
+    allocation-unit-to-node scaling
+    (:func:`repro.network.geometry.scaled_node_dims`).
     """
 
     dims: Tuple[int, ...]
     link_bw: float
+    #: Whether two disjoint boxes can load the same link.  False when every
+    #: minimal path between cells of a box stays inside the box — then
+    #: there is no cross-placement background field and placement scoring
+    #: has nothing to rank.
+    boxes_share_links: bool
+    #: The (base, adaptive) routing modes of
+    #: :func:`repro.network.netsim.build_paths`; the first is the default.
+    routing_modes: Tuple[str, str]
+    #: The :mod:`repro.network.patterns` traffic (by function name) whose
+    #: contention separates box geometries on this fabric:
+    #: :meth:`exchange_max_load` is its closed form.
+    exchange_pattern: str
+    #: Whether a length-2 dimension has two parallel links (the Blue
+    #: Gene/Q torus) — netsim's ``double_link_on_2`` for this fabric.
+    double_link_on_2: bool
 
     @property
     def num_cells(self) -> int:
@@ -112,6 +150,106 @@ class Fabric(abc.ABC):
         """Flat cell indices adjacent to ``cell`` (sorted, unique)."""
         return self.links().neighbors_of(cell)
 
+    # -- isomorphism and boxes -------------------------------------------------
+    @abc.abstractmethod
+    def canonical(self) -> "Fabric":
+        """The same graph with ``dims`` sorted descending (per-dimension
+        parameters permuted along, stable on ties) — the form the
+        isoperimetry engine's tables are keyed by."""
+
+    @abc.abstractmethod
+    def sub_fabric(self, sides: Sequence[int]) -> "Fabric":
+        """The fabric of an aligned sub-box with these sides (matched to
+        this fabric's dimensions tightest-fit); ``ValueError`` if it does
+        not fit."""
+
+    def check_unit_node_dims(self, unit_node_dims: Optional[Sequence[int]]) -> None:
+        """Raise ``ValueError`` where allocation units cannot be scaled to
+        nodes; a no-op on fabrics with the torus node convention."""
+
+    @abc.abstractmethod
+    def box_bisections(
+        self, geometries, unit_node_dims: Optional[Sequence[int]] = None
+    ) -> np.ndarray:
+        """Internal bisection (links) of a box of each geometry (rows of
+        ``geometries``) as a partition of its own — the quantity the
+        allocation policies rank by and record."""
+
+    # -- the geometry-sensitive exchange -------------------------------------
+    @abc.abstractmethod
+    def exchange_box(
+        self, geometry_: Sequence[int], unit_node_dims: Optional[Sequence[int]] = None
+    ) -> "Fabric":
+        """The fabric a box of ``geometry_`` runs its contention benchmark
+        (:attr:`exchange_pattern`) on."""
+
+    @abc.abstractmethod
+    def exchange_max_load(self) -> float:
+        """Max effective link load of :attr:`exchange_pattern` on this
+        fabric at unit volume per message (closed form, exact)."""
+
+    @abc.abstractmethod
+    def pairing_time(self, bytes_per_node: float, node_dims: Sequence[int]) -> float:
+        """Seconds to drain a bisection-pairing exchange of
+        ``bytes_per_node`` bytes per node on this fabric, whose partition
+        stands for the node-level dims ``node_dims`` (the planner's
+        pairing-stress price)."""
+
+    @abc.abstractmethod
+    def ring_equivalent(self) -> "TorusFabric":
+        """The wrapped torus on which ring collectives over this fabric's
+        dimensions cost the same — what the collective cost model and the
+        rank mapper price."""
+
+    # -- edge-isoperimetry ----------------------------------------------------
+    @abc.abstractmethod
+    def line_cuts(self, sides: np.ndarray) -> np.ndarray:
+        """Per entry ``sides[i, k]``: the links cut by a segment of that
+        many cells of one dimension-k line.  An aligned box of volume
+        ``t`` cuts ``sum_k (t / s_k) * line_cuts[k]`` links — the product
+        graph's cut decomposes per dimension."""
+
+    @abc.abstractmethod
+    def subset_cut_bound(self, t: int) -> float:
+        """Lower bound on the cut of any ``t``-cell set (complement
+        symmetry built in)."""
+
+    # -- per-link load layout -------------------------------------------------
+    @property
+    @abc.abstractmethod
+    def load_shape(self) -> Tuple[int, ...]:
+        """Shape of a per-link load array on this fabric (the layout the
+        routing engine and :meth:`repro.network.netsim.FlowPaths.link_loads`
+        produce)."""
+
+    @abc.abstractmethod
+    def slot_label(self, slot: int) -> Tuple[int, int, Tuple[int, ...]]:
+        """``(dim, direction, cell)`` of a flat load index: the link
+        leaving ``cell`` along ``dim``; ``direction`` is 0 (+1) or 1 (-1)
+        on a torus and the destination coordinate on HyperX."""
+
+    def internal_links(self, member: np.ndarray) -> np.ndarray:
+        """Boolean array of :attr:`load_shape`: the links with both
+        endpoints in the cell set ``member`` (a ``dims``-shaped mask)."""
+        table = self.links()
+        flat = np.asarray(member, dtype=bool).ravel()
+        own = np.zeros(table.n_slots, dtype=bool)
+        own[table.link[flat[table.src] & flat[table.dst]]] = True
+        return own.reshape(self.load_shape)
+
+
+def as_fabric(fabric) -> Fabric:
+    """The :class:`Fabric` a public entry point works on: a fabric as
+    given; plain dims or a :class:`Torus` become ``TorusFabric.bgq(dims)``
+    (the paper's torus, with the dims' order kept).
+
+    >>> as_fabric((4, 2))
+    TorusFabric(dims=(4, 2), wrap=(True, True), link_bw=50000000000.0, double_link_on_2=True)
+    """
+    if isinstance(fabric, Fabric):
+        return fabric
+    return TorusFabric.bgq(getattr(fabric, "dims", fabric))
+
 
 @dataclass(frozen=True)
 class TorusFabric(Fabric):
@@ -129,12 +267,26 @@ class TorusFabric(Fabric):
     >>> chain = TorusFabric.tpu((4, 2), wrap=(True, False))
     >>> chain.bisection_links()  # unwrapped dim is cut once, not twice
     4
+
+    The partition facts the allocator ranks and records by
+    (:meth:`box_bisections`, :meth:`exchange_box`, :meth:`line_cuts`,
+    :meth:`subset_cut_bound`, :attr:`degree`) follow the paper's
+    convention whatever the flags: every partition is a fully wrapped
+    torus with double links on length-2 dimensions.  :meth:`sub_fabric`,
+    :meth:`exchange_max_load` and :meth:`pairing_time` keep this fabric's
+    own link convention, as the planner prices it.
     """
 
     dims: Tuple[int, ...]
     wrap: Tuple[bool, ...]  # wrap-around link present per dimension
     link_bw: float = DEFAULT_LINK_BW  # bytes/s per link per direction
     double_link_on_2: bool = False  # Blue Gene/Q: True, TPU: False
+
+    # A ring wraps traffic of a box through cells outside it (an antipodal
+    # tie splits both ways round), so disjoint boxes can share links.
+    boxes_share_links = True
+    routing_modes = ("dor", "adaptive")
+    exchange_pattern = "bisection_pairing"
 
     def __post_init__(self):
         if len(self.dims) != len(self.wrap):
@@ -216,7 +368,112 @@ class TorusFabric(Fabric):
         """All canonical cuboid geometries of ``size`` units that fit."""
         return geometry.sub_cuboids(self.dims, size)
 
+    @property
+    def degree(self) -> int:
+        """Links per cell of the fully wrapped double-link torus on these
+        dims (two per non-trivial dimension)."""
+        return geometry.degree(self.dims)
+
     # -- the Fabric interface --------------------------------------------------
+    def canonical(self) -> "TorusFabric":
+        """The same torus with ``dims`` sorted descending (wrap flags
+        permuted along)."""
+        order = sorted(range(len(self.dims)), key=lambda k: -self.dims[k])
+        if order == list(range(len(self.dims))):
+            return self
+        return TorusFabric(
+            tuple(self.dims[k] for k in order),
+            tuple(self.wrap[k] for k in order),
+            self.link_bw,
+            self.double_link_on_2,
+        )
+
+    def sub_fabric(self, sides: Sequence[int]) -> "TorusFabric":
+        """A sub-box kept as its own fully wrapped torus — the Blue Gene/Q
+        partition semantics — with this fabric's link convention and
+        bandwidth.  (TPU slices wrap only where they span a wrapped pod
+        dimension: that is :func:`slice_fabric`.)
+
+        >>> TorusFabric.bgq((4, 4, 2)).sub_fabric((2, 4)).dims
+        (4, 2, 1)
+        """
+        g, _ = _fit_sides(self.dims, sides, "sub-box", "fabric")
+        return TorusFabric(g, (True,) * len(g), self.link_bw, self.double_link_on_2)
+
+    def box_bisections(
+        self, geometries, unit_node_dims: Optional[Sequence[int]] = None
+    ) -> np.ndarray:
+        """Each box as its own fully wrapped double-link torus, at node
+        level when ``unit_node_dims`` is given (the paper's Tables 4-7).
+        Even longest node dimensions are closed-form ``2N/L`` in one
+        vectorized pass; odd ones take the exact search of
+        :func:`repro.network.geometry.bisection_links`."""
+        geoms = np.asarray(geometries, dtype=np.int64)
+        unit = None if unit_node_dims is None else tuple(int(u) for u in unit_node_dims)
+        if unit is not None and len(unit) < len(self.dims):
+            raise ValueError(
+                f"unit_node_dims {unit} has fewer dims than the machine {self.dims}; "
+                f"every allocation-unit dimension needs a node-scale factor"
+            )
+        n_total = geoms.prod(axis=1)
+        if unit is None:
+            node = geoms
+            extras_max = 0
+        else:
+            node = geoms * np.array(unit[: geoms.shape[1]], dtype=np.int64)[None, :]
+            extras_max = max(unit[geoms.shape[1]:], default=0)
+            n_total = n_total * math.prod(unit)
+        L = np.maximum(node.max(axis=1), extras_max)
+        bis = np.zeros(len(geoms), dtype=np.int64)
+        even = (L % 2 == 0) & (L > 1)
+        bis[even] = 2 * n_total[even] // L[even]
+        for i in np.nonzero((~even) & (L > 1))[0]:
+            g = tuple(int(x) for x in geoms[i])
+            bis[i] = geometry.bisection_links(scaled_node_dims(g, unit))
+        return bis
+
+    def exchange_box(
+        self, geometry_: Sequence[int], unit_node_dims: Optional[Sequence[int]] = None
+    ) -> "TorusFabric":
+        """The box's node-level torus in the paper's convention: the
+        bisection-pairing benchmark runs there."""
+        return TorusFabric.bgq(scaled_node_dims(geometry_, unit_node_dims), self.link_bw)
+
+    def exchange_max_load(self) -> float:
+        """Max link load of bisection pairing on this torus
+        (:func:`repro.network.geometry.pairing_max_load`)."""
+        return pairing_max_load(self.dims, double_link_on_2=self.double_link_on_2)
+
+    def pairing_time(self, bytes_per_node: float, node_dims: Sequence[int]) -> float:
+        """Pairing on the node torus ``node_dims`` under this fabric's link
+        convention and bandwidth."""
+        load = pairing_max_load(node_dims, double_link_on_2=self.double_link_on_2)
+        return bytes_per_node * (load / self.link_bw)
+
+    def ring_equivalent(self) -> "TorusFabric":
+        """A torus prices its own rings."""
+        return self
+
+    def line_cuts(self, sides: np.ndarray) -> np.ndarray:
+        """A ring segment short of the whole ring has two faces (a length-2
+        ring's double link counts twice); a whole ring cuts nothing."""
+        S = np.asarray(sides, dtype=np.int64)
+        return np.where(S == np.array(self.dims, dtype=np.int64)[None, :], 0, 2)
+
+    def subset_cut_bound(self, t: int) -> float:
+        """Theorem 3.1 at ``min(t, n - t)``."""
+        return theorem31_bound(self.dims, min(t, self.num_cells - t))
+
+    @property
+    def load_shape(self) -> Tuple[int, ...]:
+        """``(D, 2, *dims)``: dimension, direction (+1, -1), source cell."""
+        return (len(self.dims), 2) + tuple(self.dims)
+
+    def slot_label(self, slot: int) -> Tuple[int, int, Tuple[int, ...]]:
+        """Unravel a flat index of the ``(D, 2, *dims)`` layout."""
+        k, direction, *cell = np.unravel_index(int(slot), self.load_shape)
+        return int(k), int(direction), tuple(int(c) for c in cell)
+
     def links(self) -> LinkTable:
         """Directed ring links, ids matching the flattened ``(D, 2, *dims)``
         load-tensor layout of :func:`repro.network.routing.route_dor` (slot
@@ -333,6 +590,16 @@ class HyperXFabric(Fabric):
     link_multiplicity: Optional[Tuple[int, ...]] = None  # K_k, default all 1
     link_bw: float = DEFAULT_LINK_BW  # bytes/s per (single) link per direction
 
+    # Box closure: a minimal (or DAL) path corrects one coordinate per hop,
+    # straight to its destination value, so it never leaves the box.
+    boxes_share_links = False
+    routing_modes = ("minimal", "dal")
+    # Pairing never contends across diameter-1 lines; all-to-all stresses
+    # the internal bisection as pairing does on a torus.
+    exchange_pattern = "all_to_all"
+    # A size-2 line is one clique link; trunking folds into capacity.
+    double_link_on_2 = False
+
     def __post_init__(self):
         object.__setattr__(self, "dims", tuple(int(a) for a in self.dims))
         if any(a < 1 for a in self.dims):
@@ -413,6 +680,112 @@ class HyperXFabric(Fabric):
             n_slots=n * sum(dims),
         )
 
+    def canonical(self) -> "HyperXFabric":
+        """The same Hamming graph with ``dims`` sorted descending
+        (multiplicities permuted along)."""
+        order = sorted(range(len(self.dims)), key=lambda k: -self.dims[k])
+        if order == list(range(len(self.dims))):
+            return self
+        return HyperXFabric(
+            tuple(self.dims[k] for k in order),
+            tuple(self.link_multiplicity[k] for k in order),
+            self.link_bw,
+        )
+
+    def check_unit_node_dims(self, unit_node_dims: Optional[Sequence[int]]) -> None:
+        """Node scaling is the Blue Gene/Q torus convention; HyperX takes
+        allocation-unit boxes directly."""
+        if unit_node_dims is not None:
+            raise ValueError(
+                "unit_node_dims is the BG/Q torus node-scaling convention; "
+                "HyperX fabrics take allocation-unit boxes directly"
+            )
+
+    def box_bisections(
+        self, geometries, unit_node_dims: Optional[Sequence[int]] = None
+    ) -> np.ndarray:
+        """Each box is its own Hamming graph (:meth:`sub_fabric`), whose
+        bisection is the exact Lindsey half-set cut."""
+        self.check_unit_node_dims(unit_node_dims)
+        return np.array(
+            [
+                self.sub_fabric(tuple(int(x) for x in g)).bisection_links()
+                for g in np.asarray(geometries)
+            ],
+            dtype=np.int64,
+        )
+
+    def exchange_box(
+        self, geometry_: Sequence[int], unit_node_dims: Optional[Sequence[int]] = None
+    ) -> "HyperXFabric":
+        """The box's own sub-fabric: all-to-all runs inside it."""
+        self.check_unit_node_dims(unit_node_dims)
+        return self.sub_fabric(geometry_)
+
+    def exchange_max_load(self) -> float:
+        """All-to-all closed form
+        (:func:`repro.network.hamming.hyperx_all_to_all_max_load`)."""
+        return hamming.hyperx_all_to_all_max_load(self)
+
+    def pairing_time(self, bytes_per_node: float, node_dims: Sequence[int]) -> float:
+        """Halving-doubling partners differ in one coordinate of the split
+        (longest) dimension, so every pair has its own direct clique link:
+        one contention-free stage over a ``K_k``-trunked link.  A box is
+        priced on its own dims; ``node_dims`` equals them."""
+        sides = self.dims
+        if max(sides) <= 1:
+            return 0.0
+        k = max(range(len(sides)), key=lambda i: sides[i])
+        return bytes_per_node / (self.link_bw * self.link_multiplicity[k])
+
+    def ring_equivalent(self) -> "TorusFabric":
+        """Wrapped-torus stand-in for pricing ring schedules on this box.
+
+        A ring pass along one dim of a clique uses one direct link per hop
+        stage, exactly like a fully-wrapped torus dim — so ring-collective
+        times on ``H(S)`` equal those on the wrapped torus of the same dims
+        with per-link bandwidth ``K_k * link_bw`` (exact when the trunking
+        is uniform; the min-multiplicity floor makes the price conservative
+        otherwise).  Single links on length 2: a size-2 clique dim has its
+        ``K_k`` trunked links already counted in the bandwidth scale, not
+        the torus's two parallel wrap links.
+        """
+        bw = self.link_bw * min(self.link_multiplicity)
+        return TorusFabric(
+            self.dims, (True,) * len(self.dims), bw, double_link_on_2=False
+        )
+
+    def line_cuts(self, sides: np.ndarray) -> np.ndarray:
+        """``s`` cells of a ``K_k``-trunked clique of ``S_k`` cells cut
+        ``K_k * s * (S_k - s)`` links."""
+        S = np.asarray(sides, dtype=np.int64)
+        av = np.array(self.dims, dtype=np.int64)[None, :]
+        mult = np.array(self.link_multiplicity, dtype=np.int64)[None, :]
+        return mult * S * (av - S)
+
+    def subset_cut_bound(self, t: int) -> float:
+        """The Lindsey/edge-identity bound
+        (:func:`repro.network.hamming.hamming_subset_bound`), exact under
+        uniform link multiplicity."""
+        return float(hamming.hamming_subset_bound(self.dims, t, self.link_multiplicity))
+
+    @property
+    def load_shape(self) -> Tuple[int, ...]:
+        """Flat ``(N * sum(S_k),)``: the dense slot layout of :meth:`links`."""
+        return (self.num_cells * sum(self.dims),)
+
+    def slot_label(self, slot: int) -> Tuple[int, int, Tuple[int, ...]]:
+        """Decode ``block_k + flat(cell) * S_k + j`` (see :meth:`links`)."""
+        i = int(slot)
+        base = 0
+        for k, a in enumerate(self.dims):
+            if i < base + self.num_cells * a:
+                rel = i - base
+                cell = np.unravel_index(rel // a, self.dims)
+                return k, rel % a, tuple(int(c) for c in cell)
+            base += self.num_cells * a
+        raise ValueError(f"slot {slot} is outside the {base} slots of H{self.dims}")
+
     def sub_fabric(self, sides: Sequence[int]) -> "HyperXFabric":
         """The fabric of an aligned sub-box: any ``c_k``-subset of a
         clique dimension is itself a ``K_{c_k}`` clique, so a HyperX
@@ -420,25 +793,28 @@ class HyperXFabric(Fabric):
         enter (contrast :func:`slice_fabric`).  Sides match machine
         dimensions tightest-fit and inherit their multiplicities.
         """
-        g = canonical(sides)
-        g = g + (1,) * (len(self.dims) - len(g))
-        if len(g) > len(self.dims):
-            raise ValueError(f"sub-box {g} has more dims than fabric {self.dims}")
-        avail = sorted(range(len(self.dims)), key=lambda i: self.dims[i])
-        used = set()
-        out_dims, out_mult = [], []
-        for side in g:
-            pick = None
-            for i in avail:
-                if i not in used and self.dims[i] >= side:
-                    pick = i
-                    break
-            if pick is None:
-                raise ValueError(f"sub-box {g} does not fit in fabric {self.dims}")
-            used.add(pick)
-            out_dims.append(side)
-            out_mult.append(self.link_multiplicity[pick])
-        return HyperXFabric(tuple(out_dims), tuple(out_mult), self.link_bw)
+        g, picks = _fit_sides(self.dims, sides, "sub-box", "fabric")
+        mult = tuple(self.link_multiplicity[i] for i in picks)
+        return HyperXFabric(g, mult, self.link_bw)
+
+
+def _fit_sides(
+    dims: Sequence[int], sides: Sequence[int], what: str, where: str
+) -> Tuple[Geometry, List[int]]:
+    """Canonical sides padded to ``len(dims)``, and the dimension each is
+    matched to, tightest fit first; ``ValueError`` when they do not fit."""
+    g = canonical(sides)
+    g = g + (1,) * (len(dims) - len(g))
+    if len(g) > len(dims):
+        raise ValueError(f"{what} {g} has more dims than {where} {tuple(dims)}")
+    avail = sorted(range(len(dims)), key=lambda i: dims[i])
+    picks: List[int] = []
+    for side in g:
+        pick = next((i for i in avail if i not in picks and dims[i] >= side), None)
+        if pick is None:
+            raise ValueError(f"{what} {g} does not fit in {where} {tuple(dims)}")
+        picks.append(pick)
+    return g, picks
 
 
 # ---------------------------------------------------------------------------
@@ -464,25 +840,9 @@ def slice_fabric(pod: TorusFabric, geometry_: Sequence[int]) -> TorusFabric:
     Raises ``TypeError`` for fabrics without per-dim ring structure.
     """
     _require_ring_fabric(pod, "slice_fabric")
-    g = canonical(geometry_)
-    g = g + (1,) * (len(pod.dims) - len(g))
-    if len(g) > len(pod.dims):
-        raise ValueError(f"slice {g} has more dims than pod {pod.dims}")
-    avail = sorted(range(len(pod.dims)), key=lambda i: pod.dims[i])
-    dims, wrap = [], []
-    used = set()
-    for side in g:
-        pick = None
-        for i in avail:
-            if i not in used and pod.dims[i] >= side:
-                pick = i
-                break
-        if pick is None:
-            raise ValueError(f"slice {g} does not fit in pod {pod.dims}")
-        used.add(pick)
-        dims.append(side)
-        wrap.append(pod.wrap[pick] and side == pod.dims[pick])
-    return TorusFabric(tuple(dims), tuple(wrap), pod.link_bw, pod.double_link_on_2)
+    g, picks = _fit_sides(pod.dims, geometry_, "slice", "pod")
+    wrap = tuple(pod.wrap[i] and side == pod.dims[i] for side, i in zip(g, picks))
+    return TorusFabric(g, wrap, pod.link_bw, pod.double_link_on_2)
 
 
 def ranked_slice_geometries(pod: TorusFabric, chips: int) -> List[Tuple[Geometry, int]]:
@@ -492,18 +852,15 @@ def ranked_slice_geometries(pod: TorusFabric, chips: int) -> List[Tuple[Geometry
     single ranking backs both the geometry-only planner
     (:func:`best_slice_geometry`) and the occupancy-aware planner
     (``repro.launch.mesh.plan_slice``), so they cannot drift apart.
-    Candidates come from the isoperimetry engine's batched enumeration
-    (:func:`repro.network.isoperimetry.fitting_geometries`); each slice's
-    bisection stays the exact wrap-aware :func:`slice_fabric` computation.
-    Raises ``TypeError`` for fabrics without per-dim ring structure."""
+    Each slice's bisection is the exact wrap-aware :func:`slice_fabric`
+    computation.  Raises ``TypeError`` for fabrics without per-dim ring
+    structure."""
     _require_ring_fabric(pod, "ranked_slice_geometries")
-    from .isoperimetry import fitting_geometries
-
-    candidates = [
-        tuple(int(x) for x in row) for row in fitting_geometries(pod.dims, chips)
-    ]
     ranked = sorted(
-        ((g, slice_fabric(pod, g).bisection_links()) for g in candidates),
+        (
+            (g, slice_fabric(pod, g).bisection_links())
+            for g in geometry.sub_cuboids(pod.dims, chips)
+        ),
         key=lambda t: (-t[1], t[0]),
     )
     if not ranked:

@@ -25,7 +25,7 @@ from __future__ import annotations
 import itertools
 import math
 from dataclasses import dataclass, field
-from typing import Iterable, Iterator, List, Sequence, Tuple
+from typing import Iterable, Iterator, List, Optional, Sequence, Tuple
 
 Geometry = Tuple[int, ...]
 
@@ -213,6 +213,78 @@ def theorem31_bound(dims: Sequence[int], t: int) -> float:
         val = 2.0 * (D - r) * k ** (1.0 / (D - r)) * t ** ((D - r - 1.0) / (D - r))
         best = min(best, val)
     return best
+
+
+def scaled_node_dims(
+    geometry: Sequence[int], unit_node_dims: Optional[Sequence[int]] = None
+) -> Geometry:
+    """Node-level torus dims of a partition: each allocation-unit dimension
+    scales the node torus; extra unit dims (e.g. the Blue Gene/Q internal
+    length-2 fifth dimension) are appended.  Identity when
+    ``unit_node_dims`` is None; a unit with *fewer* dims than the geometry
+    is an error (it would silently drop allocation dimensions)."""
+    g = canonical(geometry)
+    if unit_node_dims is None:
+        return g
+    unit = tuple(int(u) for u in unit_node_dims)
+    if len(unit) < len(g):
+        raise ValueError(
+            f"unit_node_dims {unit} has fewer dims than geometry {g}; every "
+            f"allocation-unit dimension needs a node-scale factor"
+        )
+    scaled = tuple(gi * u for gi, u in zip(g, unit[: len(g)]))
+    return canonical(scaled + unit[len(g):])
+
+
+# ---------------------------------------------------------------------------
+# Ring-routing closed forms for translation-invariant patterns.
+# ---------------------------------------------------------------------------
+def uniform_offset_max_load(
+    dims: Sequence[int],
+    offset: Sequence[int],
+    vol: float = 1.0,
+    split_ties: bool = True,
+    double_link_on_2: bool = True,
+) -> float:
+    """Max directed-link load when every vertex sends vol to vertex+offset.
+
+    By translation symmetry the load is uniform per (dimension, direction):
+    an offset of delta on a ring of length a loads each link of the chosen
+    direction with ``vol * min(delta, a-delta)`` (halved when the tie is
+    split, and halved again on BG/Q double links, a == 2; TPU single-link
+    fabrics pass ``double_link_on_2=False``).
+    """
+    m = 0.0
+    for a, off in zip(dims, offset):
+        if a == 1:
+            continue
+        delta = off % a
+        if delta == 0:
+            continue
+        d = min(delta, a - delta)
+        load = vol * d
+        if 2 * d == a and split_ties:
+            load /= 2.0
+        if a == 2 and double_link_on_2:
+            load /= 2.0  # double link
+        m = max(m, load)
+    return m
+
+
+def pairing_max_load(
+    dims: Sequence[int], split_ties: bool = True, double_link_on_2: bool = True
+) -> float:
+    """Max link load of the paper's bisection-pairing benchmark at unit
+    volume: every node sends to its antipode (``a // 2`` along each
+    dimension) on the canonical torus of ``dims``.
+
+    >>> pairing_max_load((4, 4, 2)), pairing_max_load((8, 2))
+    (1.0, 2.0)
+    """
+    a = canonical(dims)
+    return uniform_offset_max_load(
+        a, tuple(x // 2 for x in a), 1.0, split_ties, double_link_on_2
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -56,6 +56,7 @@ __all__ = [
     "hamming_degree",
     "hamming_num_edges",
     "hamming_subset_bound",
+    "hyperx_all_to_all_max_load",
     "lex_cells",
     "lex_max_edges",
     "lindsey_bound",
@@ -314,3 +315,38 @@ def hamming_bisection_links(
     if n <= 1:
         return 0
     return hamming_cut_of_set(d, lex_cells(d, n // 2), mult)
+
+
+def hyperx_all_to_all_max_load(fabric, vol_per_pair: float = 1.0) -> float:
+    """Exact max effective link load of all-to-all on a HyperX fabric
+    (any object with ``dims`` and ``link_multiplicity``, e.g.
+    :class:`repro.network.fabric.HyperXFabric`).
+
+    Under minimal dimension-ordered routing the load field of all-to-all
+    is uniform within each dimension: the dim-k link out of any cell is
+    shared by exactly ``N / S_k`` ordered pairs (the pairs whose
+    intermediate cell sits there), each contributing ``vol_per_pair``, so
+
+        max load = vol_per_pair * N / min_k (S_k * K_k).
+
+    This is the HyperX analogue of
+    :func:`repro.network.routing.all_to_all_max_load` and the closed form
+    behind the allocation study's geometry ranking: covering a dimension
+    fully (``c_k == S_k`` — impossible to beat) maximises ``min_k c_k``'s
+    denominator, so *elongated* boxes minimise all-to-all contention on
+    HyperX, the exact opposite of the torus preference.  Cross-checked
+    against :func:`repro.network.routing.route_hyperx` in the test suite.
+
+    >>> from repro.network.fabric import HyperXFabric
+    >>> hyperx_all_to_all_max_load(HyperXFabric((4, 4)))
+    4.0
+    >>> hyperx_all_to_all_max_load(HyperXFabric((16, 1)))
+    1.0
+    """
+    n = volume(fabric.dims)
+    denom = min(
+        a * k for a, k in zip(fabric.dims, fabric.link_multiplicity) if a > 1
+    ) if any(a > 1 for a in fabric.dims) else None
+    if denom is None:
+        return 0.0
+    return vol_per_pair * n / denom

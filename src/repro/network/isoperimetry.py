@@ -44,14 +44,12 @@ from typing import List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import hamming
 from .backend import resolve_backend
-from .fabric import HyperXFabric
+from .fabric import as_fabric
 from .geometry import (
     Geometry,
     canonical,
     cuboid_cut,
-    degree,
     theorem31_bound,
     volume,
 )
@@ -73,29 +71,11 @@ __all__ = [
     "lemma32_cut",
     "optimal_cuboid",
     "ranked_geometries",
-    "scaled_node_dims",
     "small_set_expansion",
     "theorem31_bound",
     "worst_bisection_geometry",
     "worst_cuboid",
 ]
-
-
-def _dims_of(torus_or_dims) -> Geometry:
-    """Canonical dims of a ``Torus``/``TorusFabric``-like object or a tuple.
-
-    :class:`~repro.network.fabric.HyperXFabric` also carries ``.dims`` but
-    has clique (not ring) lines — the public entry points dispatch on the
-    type *before* reaching this helper, so a HyperX fabric is never
-    silently flattened into torus dims.
-    """
-    if isinstance(torus_or_dims, HyperXFabric):
-        raise TypeError(
-            "HyperXFabric reached a torus-only code path; use the fabric-"
-            "dispatching entry points (cut_table, optimal_cuboid, "
-            "bisection_table, advise_partition, ...)"
-        )
-    return canonical(getattr(torus_or_dims, "dims", torus_or_dims))
 
 
 def _divisors(t: int, cap: Optional[int] = None) -> np.ndarray:
@@ -178,19 +158,19 @@ def cut_table(torus_or_dims, t: int, backend: Optional[str] = None) -> CutTable:
     """Batched exact cuts of *all* cuboid geometries of volume ``t``.
 
     One divisor-meshgrid enumeration of every aligned embedding, one
-    vectorized closed-form cut evaluation (a side ``s`` embedded in torus
-    dimension ``a`` contributes ``0`` if ``s == a`` else ``2 t / s``), one
-    group-by-canonical-geometry minimisation — no per-cuboid Python loop.
-    The per-geometry values equal :func:`repro.network.geometry.cuboid_cut`
-    exactly (property-pinned against the reference oracle).
-    ``backend="xla"`` evaluates the closed-form cut scores in the compiled
-    backend (int64 arithmetic — identical values); the divisor enumeration
-    and group-by stay host-side.
-
-    On a :class:`~repro.network.fabric.HyperXFabric` the same enumeration
-    runs with the Hamming aligned-box cut closed form
-    (:func:`repro.network.hamming.hamming_cut_aligned`, evaluated
-    host-side — the xla scorer is the torus closed form):
+    vectorized closed-form cut evaluation, one group-by-canonical-geometry
+    minimisation — no per-cuboid Python loop.  An aligned box's cut is the
+    sum over dimensions of ``t / s_k`` times the cut of its side in that
+    dimension's line (:meth:`~repro.network.fabric.Fabric.line_cuts`): on
+    a torus a side ``s`` in dimension ``a`` contributes ``0`` if
+    ``s == a`` else ``2 t / s`` — equal to
+    :func:`repro.network.geometry.cuboid_cut` exactly (property-pinned
+    against the reference oracle); on a
+    :class:`~repro.network.fabric.HyperXFabric` it contributes
+    ``t K_k (S_k - s)`` (:func:`repro.network.hamming.hamming_cut_aligned`).
+    ``backend="xla"`` evaluates the sum in the compiled backend (int64
+    arithmetic — identical values); the divisor enumeration and group-by
+    stay host-side.
 
     >>> from .fabric import HyperXFabric
     >>> cut_table(HyperXFabric((4, 4)), 4).items()
@@ -201,34 +181,23 @@ def cut_table(torus_or_dims, t: int, backend: Optional[str] = None) -> CutTable:
     """
     if t < 1:
         raise ValueError(f"t must be >= 1, got {t}")
-    if isinstance(torus_or_dims, HyperXFabric):
-        fab = torus_or_dims
-        a = fab.dims
-        S = _aligned_assignments(a, t)
-        if S.shape[0] == 0:
-            return CutTable(a, t, S.reshape(0, len(a)), np.zeros(0, dtype=np.int64))
-        av = np.array(a, dtype=np.int64)
-        mult = np.array(fab.link_multiplicity, dtype=np.int64)
-        # cut of an aligned box = t * sum_k K_k (S_k - c_k): a covered
-        # dimension contributes nothing, so cuts *decrease* with side.
-        cuts = (t * mult[None, :] * (av[None, :] - S)).sum(axis=1)
-    else:
-        a = _dims_of(torus_or_dims)
-        S = _aligned_assignments(a, t)
-        if S.shape[0] == 0:
-            return CutTable(a, t, S.reshape(0, len(a)), np.zeros(0, dtype=np.int64))
-        av = np.array(a, dtype=np.int64)
-        if resolve_backend(backend) == "xla":
-            from .backend import xla_cut_scores
+    fab = as_fabric(torus_or_dims).canonical()
+    a = fab.dims
+    S = _aligned_assignments(a, t)
+    if S.shape[0] == 0:
+        return CutTable(a, t, S.reshape(0, len(a)), np.zeros(0, dtype=np.int64))
+    line = fab.line_cuts(S)
+    if resolve_backend(backend) == "xla":
+        from .backend import xla_cut_scores
 
-            cuts = xla_cut_scores(a, S, t)
-        else:
-            cuts = np.where(S == av[None, :], 0, (2 * t) // S).sum(axis=1)
+        cuts = xla_cut_scores(S, line, t)
+    else:
+        cuts = ((t // S) * line).sum(axis=1)
     G = -np.sort(-S, axis=1)  # canonical (descending) rows
     # Group by geometry via a positional integer key (base max(a)+1): a 1-D
     # unique on int64 keys, much cheaper than np.unique(axis=0)'s row-view
     # argsort, with the identical ascending-lexicographic row order.
-    base = int(av.max()) + 1
+    base = int(max(a)) + 1
     key = G[:, 0].copy()
     for j in range(1, G.shape[1]):
         key = key * base + G[:, j]
@@ -309,52 +278,33 @@ class CuboidOptimum:
         return math.isclose(self.cut, self.bound, rel_tol=1e-9)
 
 
-def _subset_bound(a: Geometry, n: int, t: int) -> float:
-    """Theorem 3.1 bound on any size-t subset's cut, via complement symmetry
-    for t > n/2: every edge leaving S enters S̄, so cut(S) == cut(S̄) and
-    the bound at min(t, n - t) applies."""
-    return theorem31_bound(a, min(t, n - t))
-
-
-def _any_subset_bound(torus_or_dims, n: int, t: int) -> float:
-    """Per-fabric lower bound on any size-t subset's cut: Theorem 3.1 on a
-    torus, the Lindsey/edge-identity bound on a Hamming graph (exact for
-    uniform link multiplicity) — both with complement symmetry built in."""
-    if isinstance(torus_or_dims, HyperXFabric):
-        return float(
-            hamming.hamming_subset_bound(
-                torus_or_dims.dims, t, torus_or_dims.link_multiplicity
-            )
-        )
-    return _subset_bound(_dims_of(torus_or_dims), n, t)
-
-
 def optimal_cuboid(torus_or_dims, t: int) -> Optional[CuboidOptimum]:
     """Exact minimum-cut cuboid of size t inside the torus (Lemma 3.3 optimum).
 
-    Accepts a ``Torus``/``TorusFabric`` or a plain dims tuple.  Returns
-    ``None`` when no cuboid of exactly ``t`` vertices fits; raises
-    ``ValueError`` for t outside (0, n].  Ties break toward the
-    lexicographically-smallest canonical geometry.
-
-    On a :class:`~repro.network.fabric.HyperXFabric` the certificate is
-    the Lindsey/edge-identity bound of :mod:`repro.network.hamming`
-    (exact under uniform link multiplicity, so ``tight`` still certifies
-    against *all* subsets, not just boxes):
+    Accepts any fabric or a plain dims tuple.  Returns ``None`` when no
+    cuboid of exactly ``t`` vertices fits; raises ``ValueError`` for t
+    outside (0, n].  Ties break toward the lexicographically-smallest
+    canonical geometry.  The certificate is the fabric's bound on any
+    subset's cut (:meth:`~repro.network.fabric.Fabric.subset_cut_bound`):
+    Theorem 3.1 on a torus; on a
+    :class:`~repro.network.fabric.HyperXFabric` the Lindsey/edge-identity
+    bound of :mod:`repro.network.hamming` (exact under uniform link
+    multiplicity, so ``tight`` still certifies against *all* subsets, not
+    just boxes):
 
     >>> opt = optimal_cuboid((4, 4, 2), 8)
     >>> opt.geometry, opt.cut, opt.tight
     ((2, 2, 2), 16, True)
     """
-    a = torus_or_dims if isinstance(torus_or_dims, HyperXFabric) else _dims_of(torus_or_dims)
-    n = volume(a.dims if isinstance(a, HyperXFabric) else a)
+    fab = as_fabric(torus_or_dims).canonical()
+    n = fab.num_cells
     if t <= 0 or t > n:
         raise ValueError(f"t must be in (0, {n}], got {t}")
-    tbl = cut_table(a, t)
+    tbl = cut_table(fab, t)
     if len(tbl) == 0:
         return None
     geom, cut = tbl.min_cut_geometry()
-    return CuboidOptimum(geom, cut, _any_subset_bound(a, n, t))
+    return CuboidOptimum(geom, cut, fab.subset_cut_bound(t))
 
 
 def worst_cuboid(torus_or_dims, t: int) -> Optional[CuboidOptimum]:
@@ -365,15 +315,15 @@ def worst_cuboid(torus_or_dims, t: int) -> Optional[CuboidOptimum]:
     bound uses complement symmetry for t > n/2, so ``tight`` is a real
     certificate instead of being vacuously True there.
     """
-    a = torus_or_dims if isinstance(torus_or_dims, HyperXFabric) else _dims_of(torus_or_dims)
-    n = volume(a.dims if isinstance(a, HyperXFabric) else a)
+    fab = as_fabric(torus_or_dims).canonical()
+    n = fab.num_cells
     if t <= 0 or t > n:
         raise ValueError(f"t must be in (0, {n}], got {t}")
-    tbl = cut_table(a, t)
+    tbl = cut_table(fab, t)
     if len(tbl) == 0:
         return None
     geom, cut = tbl.max_cut_geometry()
-    return CuboidOptimum(geom, cut, _any_subset_bound(a, n, t))
+    return CuboidOptimum(geom, cut, fab.subset_cut_bound(t))
 
 
 def small_set_expansion(torus_or_dims, t: int) -> float:
@@ -384,13 +334,13 @@ def small_set_expansion(torus_or_dims, t: int) -> float:
     only the batched per-size *minimum* cuts are needed — the historical
     version walked every cuboid of every size.
     """
-    a = _dims_of(torus_or_dims)
+    fab = as_fabric(torus_or_dims).canonical()
     if t < 1:
         raise ValueError(f"t must be >= 1, got {t}")
-    k = degree(a)
+    k = fab.degree
     best = math.inf
     for size in range(1, t + 1):
-        tbl = cut_table(a, size)
+        tbl = cut_table(fab, size)
         if len(tbl) == 0:
             continue
         cut = int(tbl.cuts.min())
@@ -423,27 +373,6 @@ def bisection_of_geometry(dims: Sequence[int]) -> int:
         # geometry.bisection_links.
         return math.ceil(theorem31_bound(a, n // 2))
     return int(tbl.cuts.min())
-
-
-def scaled_node_dims(
-    geometry: Sequence[int], unit_node_dims: Optional[Sequence[int]] = None
-) -> Geometry:
-    """Node-level torus dims of a partition: each allocation-unit dimension
-    scales the node torus; extra unit dims (e.g. the Blue Gene/Q internal
-    length-2 fifth dimension) are appended.  Identity when
-    ``unit_node_dims`` is None; a unit with *fewer* dims than the geometry
-    is an error (it would silently drop allocation dimensions)."""
-    g = canonical(geometry)
-    if unit_node_dims is None:
-        return g
-    unit = tuple(int(u) for u in unit_node_dims)
-    if len(unit) < len(g):
-        raise ValueError(
-            f"unit_node_dims {unit} has fewer dims than geometry {g}; every "
-            f"allocation-unit dimension needs a node-scale factor"
-        )
-    scaled = tuple(gi * u for gi, u in zip(g, unit[: len(g)]))
-    return canonical(scaled + unit[len(g):])
 
 
 @dataclass(frozen=True)
@@ -514,74 +443,30 @@ def bisection_table(
     units: int,
     unit_node_dims: Optional[Sequence[int]] = None,
 ) -> BisectionTable:
-    """Batched internal bisections of every ``units``-sized geometry.
+    """Batched internal bisections of every ``units``-sized geometry
+    (:meth:`~repro.network.fabric.Fabric.box_bisections`).
 
-    Even-longest-dimension geometries (after node scaling, every Blue
-    Gene/Q partition) are closed-form ``2N/L`` in one vectorized pass; odd
-    longest dimensions fall back to the engine's exact cuboid search per
-    geometry.  Raises ``ValueError`` when no cuboid of that size fits.
-
-    On a :class:`~repro.network.fabric.HyperXFabric` each box is its own
-    Hamming graph (:meth:`HyperXFabric.sub_fabric` — multiplicities
-    inherited tightest-fit), so its internal bisection comes from the
-    exact Lindsey half-set cut; ``unit_node_dims`` node scaling is the
-    BG/Q torus convention and is rejected there.
+    On a torus even-longest-dimension geometries (after node scaling,
+    every Blue Gene/Q partition) are closed-form ``2N/L`` in one
+    vectorized pass; odd longest dimensions fall back to the exact cuboid
+    search per geometry.  On a :class:`~repro.network.fabric.HyperXFabric`
+    each box is its own Hamming graph (multiplicities inherited
+    tightest-fit) with the exact Lindsey half-set cut; ``unit_node_dims``
+    node scaling is the BG/Q torus convention and is rejected there.
+    Raises ``ValueError`` when no cuboid of that size fits.
 
     >>> from .fabric import HyperXFabric
     >>> bisection_table(HyperXFabric((16, 4)), 16).ranked()
     [((16, 1), 64), ((4, 4), 16), ((8, 2), 8)]
     """
-    if isinstance(torus_or_dims, HyperXFabric):
-        if unit_node_dims is not None:
-            raise ValueError(
-                "unit_node_dims is the BG/Q torus node-scaling convention; "
-                "HyperX fabrics rank allocation-unit boxes directly"
-            )
-        fab = torus_or_dims
-        geoms = cut_table(fab, units).geometries
-        if geoms.shape[0] == 0:
-            raise ValueError(f"no box of {units} units fits in H{fab.dims}")
-        bis = np.array(
-            [
-                fab.sub_fabric(tuple(int(x) for x in g)).bisection_links()
-                for g in geoms
-            ],
-            dtype=np.int64,
-        )
-        return BisectionTable(fab.dims, units, geoms, bis, None)
-    a = _dims_of(torus_or_dims)
-    geoms = fitting_geometries(a, units)
+    fab = as_fabric(torus_or_dims).canonical()
+    fab.check_unit_node_dims(unit_node_dims)
+    geoms = fitting_geometries(fab, units)
     if geoms.shape[0] == 0:
-        raise ValueError(f"no cuboid of {units} units fits in {a}")
+        raise ValueError(f"no cuboid of {units} units fits in {fab.dims}")
+    bis = fab.box_bisections(geoms, unit_node_dims)
     unit = None if unit_node_dims is None else tuple(int(u) for u in unit_node_dims)
-    if unit is not None and len(unit) < len(a):
-        raise ValueError(
-            f"unit_node_dims {unit} has fewer dims than the machine {a}; every "
-            f"allocation-unit dimension needs a node-scale factor"
-        )
-    if unit is None:
-        node = geoms
-        n_total = units
-        extras_max = 0
-    else:
-        uvec = np.array(unit[: geoms.shape[1]], dtype=np.int64)
-        node = geoms * uvec[None, :]
-        extras = unit[geoms.shape[1]:]
-        extras_max = max(extras, default=0)
-        n_total = units * math.prod(unit)
-    L = np.maximum(node.max(axis=1), extras_max)
-    bis = np.zeros(len(geoms), dtype=np.int64)
-    even = (L % 2 == 0) & (L > 1)
-    bis[even] = 2 * n_total // L[even]
-    odd = (~even) & (L > 1)
-    for i in np.nonzero(odd)[0]:
-        if unit is None:
-            bis[i] = bisection_of_geometry(tuple(int(x) for x in geoms[i]))
-        else:
-            bis[i] = bisection_of_geometry(
-                scaled_node_dims(tuple(int(x) for x in geoms[i]), unit)
-            )
-    return BisectionTable(a, units, geoms, bis, unit)
+    return BisectionTable(fab.dims, units, geoms, bis, unit)
 
 
 def ranked_geometries(
@@ -678,20 +563,20 @@ def advise_partition(
 ) -> PartitionAdvice:
     """Advise one job size: current (or worst, when None) vs optimal geometry.
 
-    The predicted speedup is the static pairing-benchmark ratio
-    (:func:`repro.network.routing.pairing_speedup` on the node-level dims);
-    ``simulate=True`` additionally drains the pairing benchmark of both
-    geometries through the flow-level simulator and reports the measured
-    makespan ratio — for these translation-invariant patterns the two
-    agree exactly (the §7 validation property), so a divergence flags a
-    modeling bug rather than a worse prediction.
-
-    On a :class:`~repro.network.fabric.HyperXFabric` the contention
-    benchmark is all-to-all inside the box rather than bisection pairing
+    The predicted speedup is the ratio of the two boxes' contention
+    benchmark loads (:meth:`~repro.network.fabric.Fabric.exchange_box` /
+    ``exchange_max_load``): on a torus the static pairing benchmark on the
+    node-level dims (:func:`repro.network.routing.pairing_speedup`); on a
+    :class:`~repro.network.fabric.HyperXFabric` all-to-all inside the box
     — HyperX dimensions have diameter 1, so pairing never contends and
     cannot separate geometries; all-to-all stresses the internal
-    bisection exactly as the paper's benchmark does on a torus.  The
-    certificate is the Lindsey bound on the optimum's half-set cut.
+    bisection exactly as the paper's benchmark does on a torus.
+    ``simulate=True`` additionally drains that benchmark on both boxes
+    through the flow-level simulator and reports the measured makespan
+    ratio — for these translation-invariant patterns the two agree
+    exactly (the §7 validation property), so a divergence flags a
+    modeling bug rather than a worse prediction.  ``bound`` is the
+    fabric's subset bound on the optimum's half-set cut.
 
     >>> from .fabric import HyperXFabric
     >>> adv = advise_partition(HyperXFabric((16, 4)), 16, (4, 4))
@@ -707,75 +592,8 @@ def advise_partition(
     >>> round(adv.predicted_speedup, 2), adv.is_current_optimal, adv.certified
     (2.0, False, True)
     """
-    if isinstance(torus_or_dims, HyperXFabric):
-        return _advise_hyperx(
-            torus_or_dims,
-            units,
-            current_geometry,
-            unit_node_dims=unit_node_dims,
-            simulate=simulate,
-            backend=backend,
-        )
-    from .routing import pairing_speedup  # lazy: keeps this module geometry-only
-
-    a = _dims_of(torus_or_dims)
-    tbl = bisection_table(a, units, unit_node_dims)
-    opt_geom, opt_bis = tbl.best()
-    if current_geometry is None:
-        cur_geom, cur_bis = tbl.worst()
-    else:
-        cur_geom = canonical(
-            tuple(current_geometry) + (1,) * (len(a) - len(tuple(current_geometry)))
-        )
-        if volume(cur_geom) != units:
-            raise ValueError(
-                f"current geometry {cur_geom} has volume {volume(cur_geom)}, "
-                f"expected {units}"
-            )
-        cur_bis = tbl.bisection_of(cur_geom)
-    nd_cur = scaled_node_dims(cur_geom, unit_node_dims)
-    nd_opt = scaled_node_dims(opt_geom, unit_node_dims)
-    predicted = pairing_speedup(nd_cur, nd_opt)
-    simulated: Optional[float] = None
-    if simulate:
-        from .netsim import simulate_traffic
-        from .patterns import bisection_pairing
-
-        t_cur = simulate_traffic(
-            nd_cur, bisection_pairing(nd_cur), backend=backend
-        ).makespan
-        t_opt = simulate_traffic(
-            nd_opt, bisection_pairing(nd_opt), backend=backend
-        ).makespan
-        simulated = t_cur / t_opt
-    n_nodes = volume(nd_opt)
-    return PartitionAdvice(
-        units=units,
-        current_geometry=cur_geom,
-        current_bisection=cur_bis,
-        optimal_geometry=opt_geom,
-        optimal_bisection=opt_bis,
-        bound=theorem31_bound(nd_opt, n_nodes // 2),
-        predicted_speedup=predicted,
-        simulated_speedup=simulated,
-    )
-
-
-def _advise_hyperx(
-    fab: HyperXFabric,
-    units: int,
-    current_geometry: Optional[Sequence[int]],
-    *,
-    unit_node_dims: Optional[Sequence[int]],
-    simulate: bool,
-    backend: Optional[str],
-) -> PartitionAdvice:
-    """HyperX body of :func:`advise_partition`: rank boxes by internal
-    Hamming bisection, predict the all-to-all contention ratio with the
-    closed form, certify with the Lindsey half-set bound."""
-    from .routing import hyperx_all_to_all_max_load
-
-    tbl = bisection_table(fab, units, unit_node_dims)  # rejects node scaling
+    fab = as_fabric(torus_or_dims).canonical()
+    tbl = bisection_table(fab, units, unit_node_dims)
     opt_geom, opt_bis = tbl.best()
     if current_geometry is None:
         cur_geom, cur_bis = tbl.worst()
@@ -789,22 +607,22 @@ def _advise_hyperx(
                 f"expected {units}"
             )
         cur_bis = tbl.bisection_of(cur_geom)
-    sub_cur = fab.sub_fabric(cur_geom)
-    sub_opt = fab.sub_fabric(opt_geom)
-    load_cur = hyperx_all_to_all_max_load(sub_cur)
-    load_opt = hyperx_all_to_all_max_load(sub_opt)
+    box_cur = fab.exchange_box(cur_geom, unit_node_dims)
+    box_opt = fab.exchange_box(opt_geom, unit_node_dims)
+    load_cur = box_cur.exchange_max_load()
+    load_opt = box_opt.exchange_max_load()
     predicted = load_cur / load_opt if load_opt > 0.0 else 1.0
     simulated: Optional[float] = None
     if simulate:
-        from .netsim import simulate_fabric_traffic
-        from .patterns import all_to_all
+        from . import patterns
+        from .netsim import simulate_traffic
 
-        t_cur = simulate_fabric_traffic(
-            sub_cur, all_to_all(sub_cur.dims), backend=backend
-        ).makespan
-        t_opt = simulate_fabric_traffic(
-            sub_opt, all_to_all(sub_opt.dims), backend=backend
-        ).makespan
+        t_cur, t_opt = (
+            simulate_traffic(
+                box, getattr(patterns, box.exchange_pattern)(box.dims), backend=backend
+            ).makespan
+            for box in (box_cur, box_opt)
+        )
         simulated = t_cur / t_opt if t_opt > 0.0 else 1.0
     return PartitionAdvice(
         units=units,
@@ -812,11 +630,7 @@ def _advise_hyperx(
         current_bisection=cur_bis,
         optimal_geometry=opt_geom,
         optimal_bisection=opt_bis,
-        bound=float(
-            hamming.hamming_subset_bound(
-                sub_opt.dims, units // 2, sub_opt.link_multiplicity
-            )
-        ),
+        bound=box_opt.subset_cut_bound(box_opt.num_cells // 2),
         predicted_speedup=predicted,
         simulated_speedup=simulated,
     )

@@ -30,6 +30,9 @@ Model
     already uniform — which is the paper's argument for fixing partition
     geometry rather than the router.
 
+  A HyperX fabric routes ``mode="minimal"`` or ``"dal"`` instead
+  (:func:`build_paths` takes the modes from the fabric).
+
 * Each simulation step shares every link's bandwidth **max-min fairly**
   among the flows crossing it: progressive filling over the link x flow
   incidence with ``np.bincount`` sweeps — no per-packet (or per-flow)
@@ -69,9 +72,9 @@ import numpy as np
 
 from ..obs.trace import TRACER as _TRACER
 from .backend import resolve_backend
-from .fabric import HyperXFabric, Torus, TorusFabric
+from .fabric import as_fabric
 from .geometry import volume
-from .routing import _hyperx_blocks, _hyperx_flows, max_link_load
+from .routing import _hyperx_flows, max_link_load
 
 Coord = Tuple[int, ...]
 Traffic = Tuple[np.ndarray, np.ndarray, np.ndarray]
@@ -424,19 +427,47 @@ def adaptive_paths(
 
 
 def build_paths(
-    dims: Sequence[int],
+    fabric,
     traffic: Traffic,
-    mode: str = "dor",
+    mode: Optional[str] = None,
     split_ties: bool = True,
 ) -> FlowPaths:
-    """Route a ``(src, dst, vol)`` pattern with the named router
-    (``"dor"`` or ``"adaptive"``)."""
+    """Route a ``(src, dst, vol)`` pattern on any fabric (or plain torus
+    dims) with one of its :attr:`~repro.network.fabric.Fabric.routing_modes`,
+    the first by default.
+
+    A torus routes ``"dor"`` (:func:`dor_paths`) or ``"adaptive"``
+    (:func:`adaptive_paths`) in the ``(D, 2, *dims)`` link layout of
+    ``route_dor``.  HyperX routes ``"minimal"`` or ``"dal"`` with
+    :func:`repro.network.routing.route_hyperx`'s flow expansion, and the
+    paths carry the fabric's dense per-slot capacities, so the same
+    max-min-fair drain prices trunked clique links.
+    """
+    fab = as_fabric(fabric)
+    mode = mode or fab.routing_modes[0]
+    if mode not in fab.routing_modes:
+        base, adaptive = fab.routing_modes
+        raise ValueError(
+            f"unknown routing mode {mode!r}; expected {base!r} or {adaptive!r}"
+        )
     src, dst, vol = traffic
     if mode == "dor":
-        return dor_paths(dims, src, dst, vol, split_ties=split_ties)
+        return dor_paths(fab.dims, src, dst, vol, split_ties=split_ties)
     if mode == "adaptive":
-        return adaptive_paths(dims, src, dst, vol, split_ties=split_ties)
-    raise ValueError(f"unknown routing mode {mode!r}; expected 'dor' or 'adaptive'")
+        return adaptive_paths(fab.dims, src, dst, vol, split_ties=split_ties)
+    M = np.atleast_2d(np.asarray(src)).shape[0]
+    volb = np.broadcast_to(np.asarray(vol, dtype=np.float64), (M,))
+    msg, fvol, link_ids, flow_ids = _hyperx_flows(fab, src, dst, volb, mode)
+    return FlowPaths(
+        dims=fab.dims,
+        n_messages=M,
+        msg=msg,
+        vol=fvol,
+        link_ids=link_ids,
+        flow_ids=flow_ids,
+        mode=mode,
+        capacities=fab.links().dense_capacities() / fab.link_bw,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -706,17 +737,20 @@ def _simulate_flows_impl(
 
 
 def simulate_traffic(
-    dims: Sequence[int],
+    fabric,
     traffic: Traffic,
-    mode: str = "dor",
+    mode: Optional[str] = None,
     split_ties: bool = True,
     link_bw: float = 1.0,
     double_link_on_2: bool = True,
     record_utilization: bool = False,
     backend: Optional[str] = None,
 ) -> FlowSimResult:
-    """Route and drain a ``(src, dst, vol)`` pattern in one call."""
-    paths = build_paths(dims, traffic, mode=mode, split_ties=split_ties)
+    """Route (:func:`build_paths`) and drain a ``(src, dst, vol)`` pattern
+    on any fabric or plain torus dims in one call.  ``double_link_on_2``
+    applies to the torus link layout; paths that carry their fabric's own
+    capacities (HyperX) ignore it."""
+    paths = build_paths(fabric, traffic, mode=mode, split_ties=split_ties)
     return simulate_flows(
         paths,
         link_bw=link_bw,
@@ -857,7 +891,9 @@ def simulate_phases(
 # ---------------------------------------------------------------------------
 @dataclass(frozen=True)
 class RoutingComparison:
-    """DOR vs minimal-adaptive makespans for one pattern on one fabric."""
+    """Base vs adaptive routing makespans for one pattern on one fabric
+    (``dor_makespan`` holds the base mode's: DOR on a torus, minimal on
+    HyperX)."""
 
     dims: Tuple[int, ...]
     dor_makespan: float
@@ -874,7 +910,7 @@ class RoutingComparison:
 
 
 def compare_routing(
-    dims: Sequence[int],
+    fabric,
     traffic: Traffic,
     split_ties: bool = True,
     link_bw: float = 1.0,
@@ -883,122 +919,26 @@ def compare_routing(
 ) -> RoutingComparison:
     """Quantify how much of a pattern's contention routing alone recovers.
 
-    Runs the same traffic under DOR and under the minimal-adaptive router
-    and reports both makespans.  The paper's argument is geometric: for
+    Runs the same traffic under the fabric's base and adaptive routing
+    modes (torus: DOR vs minimal-adaptive; HyperX: minimal vs DAL) and
+    reports both makespans.  The paper's argument is geometric: for
     the contention its partition geometries avoid, the recovered fraction
-    here is ~0 — no minimal router can spread a uniform load field any
-    flatter — whereas geometry changes the field itself.
+    here is ~0 on both topologies — no minimal router can spread a
+    uniform load field any flatter — whereas geometry changes the field
+    itself.
     """
-    dims = tuple(int(a) for a in dims)
-    t_dor = simulate_traffic(
-        dims, traffic, mode="dor", split_ties=split_ties,
+    fab = as_fabric(fabric)
+    base_mode, adaptive_mode = fab.routing_modes
+    t_base = simulate_traffic(
+        fab, traffic, mode=base_mode, split_ties=split_ties,
         link_bw=link_bw, double_link_on_2=double_link_on_2, backend=backend,
     ).makespan
     t_adp = simulate_traffic(
-        dims, traffic, mode="adaptive", split_ties=split_ties,
-        link_bw=link_bw, double_link_on_2=double_link_on_2, backend=backend,
-    ).makespan
-    return RoutingComparison(dims=dims, dor_makespan=t_dor, adaptive_makespan=t_adp)
-
-
-# ---------------------------------------------------------------------------
-# Fabric-dispatching entry points (torus or HyperX through one API).
-# ---------------------------------------------------------------------------
-def _fabric_dims(fabric) -> Tuple[int, ...]:
-    if isinstance(fabric, (TorusFabric, Torus, HyperXFabric)):
-        return fabric.dims
-    return tuple(int(a) for a in fabric)
-
-
-def fabric_paths(
-    fabric,
-    traffic: Traffic,
-    mode: Optional[str] = None,
-    split_ties: bool = True,
-) -> FlowPaths:
-    """Route a ``(src, dst, vol)`` pattern on any fabric.
-
-    Torus fabrics (or plain dims) dispatch to :func:`build_paths` with the
-    torus routers (``mode`` ``"dor"``/``"adaptive"``, default ``"dor"``) —
-    the returned paths are identical to the historical API.  HyperX
-    fabrics route with :func:`repro.network.routing.route_hyperx`'s flow
-    expansion (``mode`` ``"minimal"``/``"dal"``, default ``"minimal"``)
-    and carry the fabric's dense per-slot capacities so the same
-    max-min-fair drain prices trunked clique links correctly.
-    """
-    if isinstance(fabric, HyperXFabric):
-        src, dst, vol = traffic
-        M = np.atleast_2d(np.asarray(src)).shape[0]
-        volb = np.broadcast_to(np.asarray(vol, dtype=np.float64), (M,))
-        msg, fvol, link_ids, flow_ids = _hyperx_flows(
-            fabric, src, dst, volb, mode or "minimal"
-        )
-        _, n_slots = _hyperx_blocks(fabric.dims)
-        return FlowPaths(
-            dims=fabric.dims,
-            n_messages=M,
-            msg=msg,
-            vol=fvol,
-            link_ids=link_ids,
-            flow_ids=flow_ids,
-            mode=mode or "minimal",
-            capacities=fabric.links().dense_capacities() / fabric.link_bw,
-        )
-    return build_paths(_fabric_dims(fabric), traffic, mode=mode or "dor", split_ties=split_ties)
-
-
-def simulate_fabric_traffic(
-    fabric,
-    traffic: Traffic,
-    mode: Optional[str] = None,
-    split_ties: bool = True,
-    link_bw: float = 1.0,
-    double_link_on_2: bool = True,
-    record_utilization: bool = False,
-    backend: Optional[str] = None,
-) -> FlowSimResult:
-    """Route and drain a pattern on any fabric in one call — the
-    fabric-generic form of :func:`simulate_traffic` (to which it is
-    bit-identical on a torus)."""
-    paths = fabric_paths(fabric, traffic, mode=mode, split_ties=split_ties)
-    return simulate_flows(
-        paths,
-        link_bw=link_bw,
-        double_link_on_2=double_link_on_2,
-        record_utilization=record_utilization,
-        backend=backend,
-    )
-
-
-def compare_fabric_routing(
-    fabric,
-    traffic: Traffic,
-    split_ties: bool = True,
-    link_bw: float = 1.0,
-    double_link_on_2: bool = True,
-    backend: Optional[str] = None,
-) -> RoutingComparison:
-    """Baseline vs adaptive routing on any fabric.
-
-    Torus: DOR vs minimal-adaptive (== :func:`compare_routing`).  HyperX:
-    minimal dimension-ordered vs DAL.  Either way ``recovered_fraction``
-    answers the paper's question — how much of the pattern's contention
-    can routing alone remove?  ~0 for steady translation-invariant
-    patterns on both topologies; positive only for skewed fields.
-    """
-    base_mode, adp_mode = (
-        ("minimal", "dal") if isinstance(fabric, HyperXFabric) else ("dor", "adaptive")
-    )
-    t_base = simulate_fabric_traffic(
-        fabric, traffic, mode=base_mode, split_ties=split_ties,
-        link_bw=link_bw, double_link_on_2=double_link_on_2, backend=backend,
-    ).makespan
-    t_adp = simulate_fabric_traffic(
-        fabric, traffic, mode=adp_mode, split_ties=split_ties,
+        fab, traffic, mode=adaptive_mode, split_ties=split_ties,
         link_bw=link_bw, double_link_on_2=double_link_on_2, backend=backend,
     ).makespan
     return RoutingComparison(
-        dims=_fabric_dims(fabric), dor_makespan=t_base, adaptive_makespan=t_adp
+        dims=fab.dims, dor_makespan=t_base, adaptive_makespan=t_adp
     )
 
 
@@ -1011,12 +951,9 @@ __all__ = [
     "UtilizationSample",
     "adaptive_paths",
     "build_paths",
-    "compare_fabric_routing",
     "compare_routing",
     "dor_paths",
-    "fabric_paths",
     "link_capacities",
-    "simulate_fabric_traffic",
     "simulate_flows",
     "simulate_phases",
     "simulate_traffic",

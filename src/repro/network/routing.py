@@ -21,9 +21,14 @@ Three levels of machinery:
 * :class:`LinkLoads` — the historical accumulate-then-query API, now backed
   by the vectorized engine (the old per-hop walker survives only as a test
   reference under ``tests/reference_dor.py``).
-* ``uniform_offset_max_load`` / ``all_to_all_max_load`` — O(D) closed forms
+* :func:`all_to_all_max_load` (and, in :mod:`repro.network.geometry`,
+  ``uniform_offset_max_load`` / ``pairing_max_load``) — O(D) closed forms
   for translation-invariant patterns, exact by symmetry, cross-checked
   against the engine in the test suite.
+
+The HyperX engine (:func:`route_hyperx`, minimal and DAL) lives here too;
+:func:`repro.network.netsim.build_paths` picks the engine by the fabric's
+routing mode.
 
 Tie-breaking: when the hop distance along a ring is exactly half the ring
 length, minimal routing may use either direction.  ``split_ties=True``
@@ -44,8 +49,8 @@ from typing import Iterable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .backend import resolve_backend
-from .geometry import canonical, volume
-from .fabric import HyperXFabric, Torus, TorusFabric
+from .fabric import HyperXFabric
+from .geometry import bisection_links, canonical, pairing_max_load, volume
 
 Coord = Tuple[int, ...]
 
@@ -54,16 +59,13 @@ __all__ = [
     "LinkLoads",
     "PairingPrediction",
     "all_to_all_max_load",
-    "hyperx_all_to_all_max_load",
     "hyperx_max_link_load",
     "max_link_load",
     "pairing_speedup",
     "predict_pairing_time",
     "route_dor",
     "route_hyperx",
-    "route_pattern",
     "simulate_pattern",
-    "uniform_offset_max_load",
 ]
 
 
@@ -315,38 +317,6 @@ def simulate_pattern(
 # ---------------------------------------------------------------------------
 # Closed forms for translation-invariant patterns.
 # ---------------------------------------------------------------------------
-def uniform_offset_max_load(
-    dims: Sequence[int],
-    offset: Sequence[int],
-    vol: float = 1.0,
-    split_ties: bool = True,
-    double_link_on_2: bool = True,
-) -> float:
-    """Max directed-link load when every vertex sends vol to vertex+offset.
-
-    By translation symmetry the load is uniform per (dimension, direction):
-    an offset of delta on a ring of length a loads each link of the chosen
-    direction with ``vol * min(delta, a-delta)`` (halved when the tie is
-    split, and halved again on BG/Q double links, a == 2; TPU single-link
-    fabrics pass ``double_link_on_2=False``).
-    """
-    m = 0.0
-    for a, off in zip(dims, offset):
-        if a == 1:
-            continue
-        delta = off % a
-        if delta == 0:
-            continue
-        d = min(delta, a - delta)
-        load = vol * d
-        if 2 * d == a and split_ties:
-            load /= 2.0
-        if a == 2 and double_link_on_2:
-            load /= 2.0  # double link
-        m = max(m, load)
-    return m
-
-
 def all_to_all_max_load(
     dims: Sequence[int],
     vol_per_pair: float = 1.0,
@@ -416,18 +386,15 @@ def predict_pairing_time(
     double_link_on_2: bool = True,
 ) -> PairingPrediction:
     """Predicted completion time of one round of the pairing benchmark."""
-    from .patterns import furthest_offset
-
     dims = canonical(dims)
-    off = furthest_offset(dims)
-    load = uniform_offset_max_load(
-        dims, off, 1.0, split_ties=split_ties, double_link_on_2=double_link_on_2
+    load = pairing_max_load(
+        dims, split_ties=split_ties, double_link_on_2=double_link_on_2
     )
     return PairingPrediction(
         dims=dims,
         max_link_load=load,
         time_per_volume=load / link_bw_bytes_s,
-        bisection_links=Torus(dims).bisection_links(),
+        bisection_links=bisection_links(dims),
     )
 
 
@@ -640,82 +607,3 @@ def hyperx_max_link_load(fabric: HyperXFabric, loads: np.ndarray) -> float:
             m = max(m, float(block.max()) / fabric.link_multiplicity[k])
         base += n * a
     return m
-
-
-def hyperx_all_to_all_max_load(fabric: HyperXFabric, vol_per_pair: float = 1.0) -> float:
-    """Exact max effective link load of all-to-all on a HyperX fabric.
-
-    Under minimal dimension-ordered routing the load field of all-to-all
-    is uniform within each dimension: the dim-k link out of any cell is
-    shared by exactly ``N / S_k`` ordered pairs (the pairs whose
-    intermediate cell sits there), each contributing ``vol_per_pair``, so
-
-        max load = vol_per_pair * N / min_k (S_k * K_k).
-
-    This is the HyperX analogue of :func:`all_to_all_max_load` and the
-    closed form behind the allocation study's geometry ranking: covering
-    a dimension fully (``c_k == S_k`` — impossible to beat) maximises
-    ``min_k c_k``'s denominator, so *elongated* boxes minimise all-to-all
-    contention on HyperX, the exact opposite of the torus preference.
-    Cross-checked against :func:`route_hyperx` in the test suite.
-
-    >>> hyperx_all_to_all_max_load(HyperXFabric((4, 4)))
-    4.0
-    >>> hyperx_all_to_all_max_load(HyperXFabric((16, 1)))
-    1.0
-    """
-    n = volume(fabric.dims)
-    denom = min(
-        a * k for a, k in zip(fabric.dims, fabric.link_multiplicity) if a > 1
-    ) if any(a > 1 for a in fabric.dims) else None
-    if denom is None:
-        return 0.0
-    return vol_per_pair * n / denom
-
-
-# ---------------------------------------------------------------------------
-# The single fabric-dispatch entry point.
-# ---------------------------------------------------------------------------
-def route_pattern(
-    fabric,
-    src: np.ndarray,
-    dst: np.ndarray,
-    vol,
-    *,
-    mode: Optional[str] = None,
-    split_ties: bool = True,
-    backend: Optional[str] = None,
-) -> np.ndarray:
-    """Route a message batch on any fabric — one dispatch for the stack.
-
-    * :class:`~repro.network.fabric.TorusFabric` / :class:`Torus` / plain
-      dims: dimension-ordered torus routing, returning :func:`route_dor`'s
-      ``(D, 2, *dims)`` tensor **bit-for-bit** (``mode`` must be ``"dor"``
-      or ``None``; the adaptive torus router lives in
-      :mod:`repro.network.netsim`, where path state exists).
-    * :class:`~repro.network.fabric.HyperXFabric`: the flat HyperX load
-      vector of :func:`route_hyperx` (``mode`` ``"minimal"`` (default) or
-      ``"dal"``; ``split_ties``/``backend`` do not apply — clique hops
-      have no antipodal ties).
-
-    >>> import numpy as np
-    >>> from .fabric import TorusFabric
-    >>> t = route_pattern(TorusFabric.bgq((4, 4)), np.array([[0, 0]]),
-    ...                   np.array([[2, 0]]), 1.0)
-    >>> t.shape
-    (2, 2, 4, 4)
-    """
-    if isinstance(fabric, HyperXFabric):
-        if backend not in (None, "numpy"):
-            raise ValueError("HyperX routing is numpy-only; backend must be None/'numpy'")
-        return route_hyperx(fabric, src, dst, vol, mode=mode or "minimal")
-    if isinstance(fabric, (TorusFabric, Torus)):
-        dims = fabric.dims
-    else:
-        dims = tuple(int(a) for a in fabric)
-    if mode not in (None, "dor"):
-        raise ValueError(
-            f"torus route_pattern supports mode='dor' only (got {mode!r}); "
-            f"adaptive torus routing lives in repro.network.netsim"
-        )
-    return route_dor(dims, src, dst, vol, split_ties=split_ties, backend=backend)

@@ -79,11 +79,9 @@ from .allocation import (
     ScheduledJob,
     SimulationResult,
 )
-from .fabric import HyperXFabric
 from .geometry import Geometry
-from .isoperimetry import best_bisection_geometry, scaled_node_dims
+from .isoperimetry import best_bisection_geometry
 from .placement import FitTest, first_fit, placement_cells
-from .routing import hyperx_all_to_all_max_load, predict_pairing_time
 
 Coord = Tuple[int, ...]
 
@@ -224,11 +222,7 @@ class SchedulerService:
     ):
         self.machine = MachineState(machine_dims, backend=backend)
         self.policy = policy
-        if unit_node_dims is not None and isinstance(self.machine.fabric, HyperXFabric):
-            raise ValueError(
-                "unit_node_dims is the BG/Q torus node-scaling convention; "
-                "HyperX machines schedule allocation-unit boxes directly"
-            )
+        self.machine.fabric.check_unit_node_dims(unit_node_dims)
         self.unit_node_dims = unit_node_dims
         self.link_bw = float(link_bw)
         self.backfill = bool(backfill)
@@ -551,21 +545,13 @@ class SchedulerService:
             placed = self.policy.allocate(self.machine, request)
         if placed is None:
             return False
-        if isinstance(self.machine.fabric, HyperXFabric):
-            # HyperX dimensions have diameter 1, so bisection pairing never
-            # contends; the geometry-sensitive benchmark is the box's
-            # internal all-to-all (closed form, exact).
-            pred_time = (
-                hyperx_all_to_all_max_load(
-                    self.machine.fabric.sub_fabric(placed.geometry)
-                )
-                / self.link_bw
-            )
-        else:
-            node_dims = scaled_node_dims(placed.geometry, self.unit_node_dims)
-            pred_time = predict_pairing_time(
-                node_dims, 1.0, self.link_bw
-            ).time_per_volume
+        # The box's geometry-sensitive exchange (pairing on a torus, the
+        # box all-to-all on HyperX), closed form, seconds per unit volume.
+        pred_time = (
+            self.machine.fabric.exchange_box(placed.geometry, self.unit_node_dims)
+            .exchange_max_load()
+            / self.link_bw
+        )
         opt_bis = self._optimal_bisection(request.units)
         job = ScheduledJob(
             request=request,
@@ -670,7 +656,7 @@ class SchedulerService:
         if units not in self._opt_bisection:
             try:
                 self._opt_bisection[units] = best_bisection_geometry(
-                    self.machine.fabric_or_dims, units
+                    self.machine.fabric, units
                 )[1]
             except ValueError:
                 self._opt_bisection[units] = 0

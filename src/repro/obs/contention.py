@@ -153,57 +153,30 @@ class ContentionReport:
         return text
 
 
-def _own_link_mask(
-    dims: Tuple[int, ...], oriented: Sequence[int], offset: Sequence[int]
-) -> np.ndarray:
-    """(D, 2, *dims) bool: links whose both endpoints are the job's cells."""
-    from repro.network.placement import placement_cells
-
-    cells = np.zeros(dims, dtype=bool)
-    cells[placement_cells(dims, tuple(oriented), tuple(offset))] = True
-    D = len(dims)
-    mask = np.zeros((D, 2) + dims, dtype=bool)
-    for k in range(D):
-        fwd = cells & np.roll(cells, -1, axis=k)  # link cell -> cell+1
-        mask[k, 0] = fwd
-        mask[k, 1] = np.roll(fwd, 1, axis=k)  # link cell -> cell-1
-    return mask
-
-
 def _advise(
-    dims_or_fabric,
+    fabric,
     units: int,
     geometry: Tuple[int, ...],
     unit_node_dims: Optional[Sequence[int]],
 ) -> Tuple[Optional[Tuple[int, ...]], float, float, float, float, bool]:
     """(optimal_geometry, pairing_load, optimal_load, bound, ratio,
-    certified) for one partition, via the isoperimetry advisor.  Accepts
-    torus dims or a :class:`~repro.network.fabric.HyperXFabric`, whose
-    contention benchmark is the box-internal all-to-all (pairing never
-    contends across diameter-1 dimensions)."""
-    from repro.network.fabric import HyperXFabric
-    from repro.network.isoperimetry import advise_partition, scaled_node_dims
-    from repro.network.routing import (
-        hyperx_all_to_all_max_load,
-        predict_pairing_time,
-    )
+    certified) for one partition, via the isoperimetry advisor.  The loads
+    are the fabric's contention benchmark on each box
+    (:meth:`~repro.network.fabric.Fabric.exchange_box`): pairing on a
+    torus, the box-internal all-to-all on HyperX (pairing never contends
+    across diameter-1 dimensions)."""
+    from repro.network.isoperimetry import advise_partition
 
     try:
         advice = advise_partition(
-            dims_or_fabric, units, geometry, unit_node_dims=unit_node_dims
+            fabric, units, geometry, unit_node_dims=unit_node_dims
         )
     except ValueError:
         return None, 0.0, 0.0, 0.0, 1.0, False
-    if isinstance(dims_or_fabric, HyperXFabric):
-        cur_load = hyperx_all_to_all_max_load(dims_or_fabric.sub_fabric(geometry))
-        opt_load = hyperx_all_to_all_max_load(
-            dims_or_fabric.sub_fabric(advice.optimal_geometry)
-        )
-    else:
-        cur_nodes = scaled_node_dims(geometry, unit_node_dims)
-        opt_nodes = scaled_node_dims(advice.optimal_geometry, unit_node_dims)
-        cur_load = predict_pairing_time(cur_nodes, 1.0, 1.0).max_link_load
-        opt_load = predict_pairing_time(opt_nodes, 1.0, 1.0).max_link_load
+    cur_load = fabric.exchange_box(geometry, unit_node_dims).exchange_max_load()
+    opt_load = fabric.exchange_box(
+        advice.optimal_geometry, unit_node_dims
+    ).exchange_max_load()
     return (
         tuple(advice.optimal_geometry),
         float(cur_load),
@@ -211,121 +184,6 @@ def _advise(
         float(advice.bound),
         float(advice.predicted_speedup),
         bool(advice.certified),
-    )
-
-
-def _attribute_hyperx(
-    fabric,
-    loads_by_job: Dict[int, np.ndarray],
-    placements: Dict[int, Any],
-    *,
-    top_hotspots: int = 5,
-) -> ContentionReport:
-    """HyperX body of :func:`attribute_traffic`: flat per-slot load
-    vectors in the dense link layout of ``fabric.links()``.  The hotspot
-    records reuse :class:`HotspotLink` with HyperX semantics —
-    ``direction`` is the destination *coordinate* of the clique link, not
-    a torus +/- direction."""
-    from repro.network.placement import placement_cells
-
-    dims = fabric.dims
-    n = int(np.prod(dims))
-    table = fabric.links()
-    n_slots = table.n_slots
-    total = np.zeros(n_slots, dtype=np.float64)
-    jobs: List[JobContention] = []
-    cross_total = 0.0
-    for jid in sorted(loads_by_job):
-        loads = np.asarray(loads_by_job[jid], dtype=np.float64)
-        if loads.shape != (n_slots,):
-            raise ValueError(
-                f"job {jid} loads must have shape ({n_slots},) for H{dims}; "
-                f"got {loads.shape}"
-            )
-        total += loads
-        p = placements.get(jid)
-        if p is not None:
-            oriented = tuple(int(w) for w in p.oriented)
-            offset = tuple(int(o) for o in p.offset)
-            geometry = tuple(int(g) for g in p.geometry)
-            units = int(np.prod(oriented))
-            member = np.zeros(dims, dtype=bool)
-            member[placement_cells(dims, oriented, offset)] = True
-            member = member.ravel()
-            own = np.zeros(n_slots, dtype=bool)
-            both = member[table.src] & member[table.dst]
-            own[table.link[both]] = True
-            self_load = float(loads[own].sum())
-            cross_load = float(loads[~own].sum())
-            opt_geom, cur_load, opt_load, bound, ratio, certified = _advise(
-                fabric, units, geometry, None
-            )
-        else:
-            oriented = offset = geometry = ()
-            units = 0
-            self_load = float(loads.sum())
-            cross_load = 0.0
-            opt_geom, cur_load, opt_load, bound, ratio, certified = (
-                None, 0.0, 0.0, 0.0, 1.0, False,
-            )
-        cross_total += cross_load
-        jobs.append(
-            JobContention(
-                job_id=int(jid),
-                units=units,
-                geometry=geometry,
-                oriented=oriented,
-                offset=offset,
-                self_load=self_load,
-                cross_load=cross_load,
-                max_link_load=float(loads.max()) if loads.size else 0.0,
-                pairing_load=cur_load,
-                optimal_geometry=opt_geom,
-                optimal_max_load=opt_load,
-                bound=bound,
-                avoidable_ratio=ratio,
-                certified=certified,
-            )
-        )
-
-    bases: List[int] = []
-    b = 0
-    for a in dims:
-        bases.append(b)
-        b += n * a
-    hotspots: List[HotspotLink] = []
-    if total.size and top_hotspots > 0:
-        k = min(int(top_hotspots), int((total > 0.0).sum()))
-        if k > 0:
-            idx = np.argpartition(total, -k)[-k:]
-            idx = idx[np.argsort(-total[idx], kind="stable")]
-            for i in idx:
-                i = int(i)
-                kdim = max(d for d in range(len(dims)) if bases[d] <= i)
-                rel = i - bases[kdim]
-                cell = np.unravel_index(rel // dims[kdim], dims)
-                j = rel % dims[kdim]
-                shares = {}
-                for jid in sorted(loads_by_job):
-                    share = float(np.asarray(loads_by_job[jid])[i])
-                    if share > 0.0:
-                        shares[int(jid)] = share
-                hotspots.append(
-                    HotspotLink(
-                        dim=int(kdim),
-                        direction=int(j),  # destination coordinate (HyperX)
-                        cell=tuple(int(c) for c in cell),
-                        load=float(total[i]),
-                        shares=shares,
-                    )
-                )
-    return ContentionReport(
-        dims=dims,
-        jobs=tuple(jobs),
-        hotspots=tuple(hotspots),
-        total_load=float(total.sum()),
-        max_link_load=float(total.max()) if total.size else 0.0,
-        cross_load=cross_total,
     )
 
 
@@ -339,36 +197,37 @@ def attribute_traffic(
     top_hotspots: int = 5,
 ) -> ContentionReport:
     """Build a :class:`ContentionReport` from explicit per-job load
-    tensors (each ``(D, 2, *dims)`` — e.g. a netsim result's
-    ``link_loads`` split by the job that injected each flow).
+    arrays, each in the fabric's link layout
+    (:attr:`~repro.network.fabric.Fabric.load_shape`: ``(D, 2, *dims)``
+    on a torus — e.g. a netsim result's ``link_loads`` split by the job
+    that injected each flow).
 
     ``placements`` optionally maps job ids to
     :class:`~repro.network.allocation.Placement` records; with them the
     self/cross split and the avoidable-contention gauge are computed,
     without them the report is attribution-only (geometry fields empty).
 
-    Passing a :class:`~repro.network.fabric.HyperXFabric` as ``fabric``
-    switches to flat per-slot load vectors in the fabric's dense link
-    layout (see :func:`_attribute_hyperx`); ``dims`` is then ignored in
-    favour of the fabric's own.
+    ``fabric`` (default: the torus of ``dims``) sets the layout; a
+    :class:`~repro.network.fabric.HyperXFabric` takes flat per-slot
+    vectors in its dense link layout, and its hotspot ``direction`` is
+    the destination *coordinate* of the clique link.  ``dims`` is then
+    ignored in favour of the fabric's own.
     """
-    from repro.network.fabric import HyperXFabric
+    from repro.network.fabric import as_fabric
+    from repro.network.placement import placement_cells
 
-    if isinstance(fabric, HyperXFabric):
-        return _attribute_hyperx(
-            fabric, loads_by_job, placements or {}, top_hotspots=top_hotspots
-        )
-    dims = tuple(int(a) for a in dims)
-    D = len(dims)
+    fab = as_fabric(dims if fabric is None else fabric)
+    dims = fab.dims
+    shape = fab.load_shape
     placements = placements or {}
-    total = np.zeros((D, 2) + dims, dtype=np.float64)
+    total = np.zeros(shape, dtype=np.float64)
     jobs: List[JobContention] = []
     cross_total = 0.0
     for jid in sorted(loads_by_job):
         loads = np.asarray(loads_by_job[jid], dtype=np.float64)
-        if loads.shape != (D, 2) + dims:
+        if loads.shape != shape:
             raise ValueError(
-                f"job {jid} loads must have shape {(D, 2) + dims}; got {loads.shape}"
+                f"job {jid} loads must have shape {shape}; got {loads.shape}"
             )
         total += loads
         p = placements.get(jid)
@@ -377,11 +236,13 @@ def attribute_traffic(
             offset = tuple(int(o) for o in p.offset)
             geometry = tuple(int(g) for g in p.geometry)
             units = int(np.prod(oriented))
-            own = _own_link_mask(dims, oriented, offset)
+            member = np.zeros(dims, dtype=bool)
+            member[placement_cells(dims, oriented, offset)] = True
+            own = fab.internal_links(member)
             self_load = float(loads[own].sum())
             cross_load = float(loads[~own].sum())
             opt_geom, cur_load, opt_load, bound, ratio, certified = _advise(
-                dims, units, geometry, unit_node_dims
+                fab, units, geometry, unit_node_dims
             )
         else:
             oriented = offset = geometry = ()
@@ -419,7 +280,7 @@ def attribute_traffic(
             idx = np.argpartition(flat, -k)[-k:]
             idx = idx[np.argsort(-flat[idx], kind="stable")]
             for i in idx:
-                kdim, direction, *cell = np.unravel_index(int(i), (D, 2) + dims)
+                kdim, direction, cell = fab.slot_label(int(i))
                 shares = {}
                 for jid in sorted(loads_by_job):
                     share = float(np.asarray(loads_by_job[jid]).ravel()[int(i)])
@@ -427,9 +288,9 @@ def attribute_traffic(
                         shares[int(jid)] = share
                 hotspots.append(
                     HotspotLink(
-                        dim=int(kdim),
-                        direction=int(direction),
-                        cell=tuple(int(c) for c in cell),
+                        dim=kdim,
+                        direction=direction,
+                        cell=cell,
                         load=float(flat[int(i)]),
                         shares=shares,
                     )
@@ -459,47 +320,35 @@ def attribute_contention(
     the scored policies stack into the background), so the per-job
     fields sum exactly to ``machine.traffic_loads()``.
 
-    A machine built over a :class:`~repro.network.fabric.HyperXFabric`
-    attributes each box's all-to-all under HyperX minimal routing
-    instead (:func:`repro.network.routing.route_hyperx`); its cross
-    traffic is structurally zero — minimal paths never leave the box —
-    so the report's gauge is purely the geometry-internal ratio.
+    On a fabric whose disjoint boxes share no links (HyperX) there is no
+    background field: each box's all-to-all is routed with the fabric's
+    base routing mode instead (:func:`repro.network.netsim.build_paths`);
+    its cross traffic is structurally zero — minimal paths never leave
+    the box — so the report's gauge is purely the geometry-internal ratio.
     """
-    from repro.network.fabric import HyperXFabric
-    from repro.network.placement import placement_cells, placement_loads
+    from repro.network.netsim import build_paths
+    from repro.network.placement import (
+        placement_all_to_all_traffic,
+        placement_loads,
+    )
 
+    fabric = machine.fabric
     dims = tuple(int(a) for a in machine.dims)
-    if isinstance(getattr(machine, "fabric", None), HyperXFabric):
-        from repro.network.routing import route_hyperx
-
-        fabric = machine.fabric
+    if fabric.boxes_share_links:
+        loads_by_job = {
+            jid: placement_loads(dims, p.oriented, p.offset)
+            for jid, p in machine.placements.items()
+        }
+    else:
         loads_by_job = {}
         for jid, p in machine.placements.items():
-            member = np.zeros(dims, dtype=bool)
-            member[placement_cells(dims, p.oriented, p.offset)] = True
-            cells = np.stack(np.nonzero(member), axis=1)
-            t = cells.shape[0]
-            si = np.repeat(np.arange(t), t)
-            di = np.tile(np.arange(t), t)
-            keep = si != di
-            loads_by_job[jid] = route_hyperx(
-                fabric, cells[si[keep]], cells[di[keep]], 1.0
-            )
-        return attribute_traffic(
-            dims,
-            loads_by_job,
-            dict(machine.placements),
-            fabric=fabric,
-            top_hotspots=top_hotspots,
-        )
-    loads_by_job = {
-        jid: placement_loads(dims, p.oriented, p.offset)
-        for jid, p in machine.placements.items()
-    }
+            src, dst, _ = placement_all_to_all_traffic(dims, p.oriented, p.offset)
+            loads_by_job[jid] = build_paths(fabric, (src, dst, 1.0)).link_loads()
     return attribute_traffic(
         dims,
         loads_by_job,
         dict(machine.placements),
+        fabric=fabric,
         unit_node_dims=unit_node_dims,
         top_hotspots=top_hotspots,
     )

@@ -34,14 +34,14 @@ from repro.network.geometry import (
 )
 
 
-def _dims_of(torus_or_dims) -> Geometry:
+def _ref_dims(torus_or_dims) -> Geometry:
     return canonical(getattr(torus_or_dims, "dims", torus_or_dims))
 
 
 def reference_cut_table(torus_or_dims, t: int) -> List[Tuple[Geometry, int]]:
     """(geometry, cut) for every fitting cuboid of volume t, lexicographically
     ascending — the per-cuboid counterpart of ``cut_table(...).items()``."""
-    a = _dims_of(torus_or_dims)
+    a = _ref_dims(torus_or_dims)
     return sorted((c, cuboid_cut(a, c)) for c in sub_cuboids(a, t))
 
 
@@ -53,7 +53,7 @@ def reference_optimal_cuboid(
     torus_or_dims, t: int
 ) -> Optional[Tuple[Geometry, int, float]]:
     """(geometry, cut, bound) of the min-cut cuboid, or None if none fits."""
-    a = _dims_of(torus_or_dims)
+    a = _ref_dims(torus_or_dims)
     n = volume(a)
     if t <= 0 or t > n:
         raise ValueError(f"t must be in (0, {n}], got {t}")
@@ -71,7 +71,7 @@ def reference_worst_cuboid(
     torus_or_dims, t: int
 ) -> Optional[Tuple[Geometry, int, float]]:
     """(geometry, cut, bound) of the max-cut cuboid, or None if none fits."""
-    a = _dims_of(torus_or_dims)
+    a = _ref_dims(torus_or_dims)
     n = volume(a)
     if t <= 0 or t > n:
         raise ValueError(f"t must be in (0, {n}], got {t}")
@@ -88,7 +88,7 @@ def reference_worst_cuboid(
 def reference_small_set_expansion(torus_or_dims, t: int) -> float:
     """h_t over cuboid witnesses by the full double loop (sizes x cuboids),
     computing the interior explicitly per cuboid."""
-    a = _dims_of(torus_or_dims)
+    a = _ref_dims(torus_or_dims)
     best = math.inf
     for size in range(1, t + 1):
         for c in sub_cuboids(a, size):
@@ -116,7 +116,7 @@ def reference_bisection_table(
 ) -> List[Tuple[Geometry, int]]:
     """(geometry, internal bisection links) per fitting geometry of a size,
     lexicographically ascending, via one ``bisection_links`` call each."""
-    a = _dims_of(torus_or_dims)
+    a = _ref_dims(torus_or_dims)
     return sorted(
         (c, bisection_links(_scaled_node_dims(c, unit_node_dims)))
         for c in sub_cuboids(a, units)

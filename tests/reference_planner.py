@@ -29,7 +29,8 @@ from repro.launch.planner import AXES, HBM_BYTES, ORDER_HINT
 from repro.network.collectives import COLLECTIVE_TIME, AxisEmbedding
 from repro.network.fabric import TorusFabric, ranked_slice_geometries, slice_fabric
 from repro.network.geometry import canonical, volume
-from repro.network.isoperimetry import ranked_geometries, scaled_node_dims
+from repro.network.geometry import scaled_node_dims
+from repro.network.isoperimetry import ranked_geometries
 from repro.network.mapping import (
     axis_order_coords,
     axis_permutation_orders,

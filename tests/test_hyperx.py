@@ -29,7 +29,7 @@ from repro.network import (
     MachineState,
     advise_partition,
     bisection_table,
-    compare_fabric_routing,
+    compare_routing,
     cut_table,
     hamming_bisection_links,
     hamming_cut_aligned,
@@ -39,7 +39,7 @@ from repro.network import (
     hyperx_max_link_load,
     optimal_cuboid,
     route_hyperx,
-    simulate_fabric_traffic,
+    simulate_traffic,
     simulate_queue,
 )
 from repro.network.backend import HAVE_JAX
@@ -158,14 +158,14 @@ def test_dal_equals_minimal_on_steady_pairing():
     a = route_hyperx(fab, src, dst, vol, mode="minimal")
     b = route_hyperx(fab, src, dst, vol, mode="dal")
     np.testing.assert_array_equal(a, b)
-    cmp = compare_fabric_routing(fab, (src, dst, vol))
+    cmp = compare_routing(fab, (src, dst, vol))
     assert cmp.recovered_fraction == 0.0
 
 
 @pytest.mark.parametrize("dims", [(8, 8), (16, 4), (8, 4)])
 def test_dal_beats_minimal_on_hotspot(dims):
     fab = HyperXFabric(dims)
-    cmp = compare_fabric_routing(fab, hotspot_line(dims))
+    cmp = compare_routing(fab, hotspot_line(dims))
     assert cmp.dor_makespan == pytest.approx(2.0)
     assert cmp.adaptive_makespan == pytest.approx(10.0 / 7.0)
     assert cmp.recovered_fraction == pytest.approx(2.0 / 7.0)
@@ -183,7 +183,7 @@ def test_all_to_all_closed_form_matches_engine_and_netsim(dims):
     loads = route_hyperx(fab, src, dst, vol)
     want = hyperx_all_to_all_max_load(fab)
     assert hyperx_max_link_load(fab, loads) == pytest.approx(want)
-    sim = simulate_fabric_traffic(fab, (src, dst, vol))
+    sim = simulate_traffic(fab, (src, dst, vol))
     assert sim.makespan == pytest.approx(want)  # steady: static == simulated
 
 
@@ -210,8 +210,8 @@ def test_hyperx_netsim_xla_parity():
     dims = (8, 4)
     fab = HyperXFabric(dims)
     src, dst, vol = hotspot_line(dims)
-    a = simulate_fabric_traffic(fab, (src, dst, vol), backend="numpy")
-    b = simulate_fabric_traffic(fab, (src, dst, vol), backend="xla")
+    a = simulate_traffic(fab, (src, dst, vol), backend="numpy")
+    b = simulate_traffic(fab, (src, dst, vol), backend="xla")
     assert a.makespan == pytest.approx(b.makespan)
     np.testing.assert_allclose(a.completion, b.completion)
 

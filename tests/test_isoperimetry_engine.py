@@ -189,7 +189,7 @@ def test_bisection_table_rejects_short_unit_node_dims():
     """A unit_node_dims with fewer dims than the machine would silently drop
     allocation dimensions — it must be a descriptive error, not a numpy
     broadcast failure."""
-    from repro.network.isoperimetry import scaled_node_dims
+    from repro.network.geometry import scaled_node_dims
 
     with pytest.raises(ValueError, match="fewer dims"):
         bisection_table((4, 4, 3, 2), 4, unit_node_dims=(2, 2))

@@ -211,7 +211,7 @@ def test_cut_scores_program_compiles(one_chip):
 
     with jax.enable_x64(True):
         backend._cut_fn().lower(
-            _spec(one_chip, (64, 3), jnp.int64), _spec(one_chip, (3,), jnp.int64)
+            _spec(one_chip, (64, 3), jnp.int64), _spec(one_chip, (64, 3), jnp.int64)
         ).compile()
 
 
