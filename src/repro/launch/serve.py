@@ -17,12 +17,22 @@ With tracing on (``repro.obs``) a call records the spans ``serve.init``
 ``serve.cache`` (cache allocation, with
 its bytes by kind), ``serve.prefill`` and ``serve.decode`` (each with its
 ``steps``); ``serve.cache_bytes{kind=...}`` counts the cache's bytes.
+
+The decode loop runs one step ahead of the host: step i+1 is queued on the
+device from step i's ids, still on the device, before the host reads those
+ids and hands them to ``on_token``.  ``serve.decode`` also carries
+``ahead``, the decode steps queued before the host had read the ids of the
+decode step before them (``gen_len - 1`` when the loop keeps ahead), and
+``wait_s``, the host's seconds blocked on the ids (close to the span's own
+length when the device sets the pace).
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
+import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Sequence
 
@@ -103,6 +113,15 @@ def cache_bytes(cache: Any) -> Dict[str, int]:
     return out
 
 
+@functools.partial(jax.jit, static_argnums=2)
+def greedy_pick(logits: jax.Array, rows: Optional[jax.Array], vocab_size: int):
+    """The greedy ids (B, 1) int32 of a step's logits (B, 1, padded vocab),
+    the argmax over the first ``vocab_size`` columns, and the logits of
+    the requests ``rows`` (None when ``rows`` is None), in one program."""
+    ids = jnp.argmax(logits[:, -1, :vocab_size], axis=-1)[:, None].astype(jnp.int32)
+    return ids, None if rows is None else logits[rows, -1]
+
+
 def serve(
     args: argparse.Namespace, params: Any = None, keep_rows: Optional[Sequence[int]] = None,
     on_token: Optional[Callable[[int, np.ndarray], None]] = None,
@@ -148,23 +167,36 @@ def serve(
     prefill_s = tm.elapsed
     prefill_logits = logits[:, -1]
 
-    # batched greedy decode
+    # batched greedy decode, one step ahead of the host: step i is queued
+    # from the ids still on the device before the host waits for them
     out_tokens = []
     kept = []
     rows = None if keep_rows is None else jnp.asarray(keep_rows, jnp.int32)
-    tok = jnp.argmax(logits[:, -1, : arch.vocab_size], axis=-1)[:, None].astype(jnp.int32)
+    tok, _ = greedy_pick(logits, rows, arch.vocab_size)
+    queued = ahead = 0
+    wait_s = 0.0
     with obs_timer("serve.decode", requests=B, tokens=args.gen_len, steps=args.gen_len) as tm:
         for i in range(args.gen_len):
-            out_tokens.append(np.asarray(tok))
-            if on_token is not None:
-                on_token(i, out_tokens[-1])
             logits, cache = decode(
                 params, cache, {"tokens": tok}, jnp.array(args.prompt_len + i)
             )
+            queued += 1
+            nxt, rows_logits = greedy_pick(logits, rows, arch.vocab_size)
             if rows is not None:
-                kept.append(logits[rows, -1])
-            tok = jnp.argmax(logits[:, -1, : arch.vocab_size], axis=-1)[:, None].astype(jnp.int32)
+                kept.append(rows_logits)
+            # column i (i > 0) is decode step i-1's ids: is step i queued
+            # before the host reads them?
+            ahead += i > 0 and queued > i
+            t0 = time.perf_counter()
+            out_tokens.append(np.asarray(tok))
+            wait_s += time.perf_counter() - t0
+            if on_token is not None:
+                on_token(i, out_tokens[-1])
+            tok = nxt
+        t0 = time.perf_counter()
         jax.block_until_ready(tok)
+        wait_s += time.perf_counter() - t0
+        tm.annotate(ahead=ahead, wait_s=wait_s)
     decode_s = tm.elapsed
 
     return Served(

@@ -143,6 +143,52 @@ def test_serve_streams_each_token_as_it_reaches_the_host():
     np.testing.assert_array_equal(np.concatenate([t for _, t in seen], axis=1), out.tokens)
 
 
+def test_serve_look_ahead_matches_a_synchronous_loop():
+    """Queued one step ahead, ``serve`` gives exactly the tokens, kept logits
+    and last logits of a loop that reads each token before the next step,
+    and ``on_token`` still sees the columns in order."""
+    args = serve.parse_args(
+        ["--arch", "zamba2-7b", "--requests", "3", "--prompt-len", "4", "--gen-len", "5"])
+    seen = []
+    out = serve.serve(args, keep_rows=[0, 2], on_token=lambda i, t: seen.append((i, t.copy())))
+    assert [i for i, _ in seen] == list(range(5))
+    np.testing.assert_array_equal(np.concatenate([t for _, t in seen], axis=1), out.tokens)
+
+    model, V = out.model, out.model.cfg.vocab_size
+    step = jax.jit(model.decode_step, donate_argnums=1)
+    cache = model.init_cache(3, 9)
+    for t in range(4):
+        logits, cache = step(out.params, cache, {"tokens": jnp.asarray(out.prompts[:, t:t + 1])},
+                             jnp.array(t))
+    tokens, kept = [], []
+    for i in range(5):
+        tokens.append(np.asarray(jnp.argmax(logits[:, -1, :V], axis=-1)[:, None].astype(jnp.int32)))
+        logits, cache = step(out.params, cache, {"tokens": jnp.asarray(tokens[-1])},
+                             jnp.array(4 + i))
+        kept.append(np.asarray(logits[jnp.array([0, 2]), -1]))
+    np.testing.assert_array_equal(out.tokens, np.concatenate(tokens, axis=1))
+    np.testing.assert_array_equal(out.kept_logits, np.stack(kept, axis=1))
+    np.testing.assert_array_equal(np.asarray(out.last_logits), np.asarray(logits[:, -1]))
+
+
+def test_serve_decode_span_counts_the_look_ahead():
+    """``serve.decode`` carries ``ahead``, every decode step but the first
+    queued before the host read the previous step's ids, and ``wait_s``,
+    the host's time blocked on ids, inside the span's own length."""
+    from repro import obs
+
+    obs.enable_tracing(clear=True)
+    try:
+        serve.serve(serve.parse_args(
+            ["--arch", "zamba2-7b", "--requests", "2", "--prompt-len", "3", "--gen-len", "6"]))
+    finally:
+        obs.disable_tracing()
+    (span,) = [e for e in obs.export_chrome_trace()["traceEvents"] if e["name"] == "serve.decode"]
+    obs.clear_telemetry()
+    assert span["args"]["ahead"] == 5
+    assert 0 < span["args"]["wait_s"] <= 1e-6 * span["dur"]
+
+
 def test_decode_step_carries_the_hybrid_scopes():
     """The profiler's names for the hybrid's parts (``jax.named_scope``)."""
     model = build_model(get_arch("zamba2-7b").reduced())
